@@ -1,5 +1,6 @@
-"""Pallas-kernel micro-bench harness: wall time per call (interpret mode on
-CPU — structural only; real numbers need a TPU) + oracle agreement.
+"""Pallas-kernel micro-bench harness: wall time per call + oracle agreement.
+The kernels run compiled on a TPU and in the Pallas interpreter elsewhere
+(structural only), so every row names the backend it ran on.
 CSV: name,us_per_call,derived."""
 from __future__ import annotations
 
@@ -55,8 +56,10 @@ def rows():
     out.append(("banked_transpose_512",
                 _time(lambda: banked_transpose(m))))
 
-    return [{"name": n, "us_per_call": round(t, 1),
-             "note": "interpret-mode CPU wall time (structural)"}
+    from repro.kernels.registry import interpret_mode
+    mode = "interpret-mode (structural)" if interpret_mode() else "compiled"
+    note = f"{mode} {jax.default_backend()} wall time"
+    return [{"name": n, "us_per_call": round(t, 1), "note": note}
             for n, t in out]
 
 

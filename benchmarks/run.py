@@ -8,7 +8,7 @@ harnesses.  Prints ``name,us_per_call,derived`` CSV (one line per cell).
   autotune  — repro.tune re-derives the paper's per-workload winners
   serving   — paged-KV serving traffic × 9 memories (docs/SERVING.md)
   cost      — batched cost engine vs per-arch loop (writes BENCH_cost.json)
-  kernels   — Pallas kernel micro-bench (interpret mode)
+  kernels   — Pallas kernel micro-bench
   roofline  — §Roofline terms from dry-run artifacts (if present)
 """
 from __future__ import annotations
@@ -25,6 +25,8 @@ def main() -> None:
     sections = sys.argv[1:] or ["table2", "table3", "table1", "fig9",
                                 "autotune", "serving", "cost", "beyond",
                                 "bankscale", "kernels", "roofline"]
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (autotune, bank_scaling, beyond_paper, cost_bench,
                             fig9_cost_perf, kernel_bench, roofline_report,
                             serving_bench, table1_area, table2_transpose,

@@ -5,7 +5,8 @@ Each kernel package ships three files:
   ops.py    — jit'd public wrapper (layout plumbing, shape checks)
   ref.py    — pure-jnp oracle used by the allclose test sweeps
 
-All kernels validate on CPU in interpret mode; BlockSpecs are chosen for the
+All kernels validate on CPU in interpret mode and run compiled on a TPU (the
+mode comes from ``registry.interpret_mode``); BlockSpecs are chosen for the
 TPU memory hierarchy (HBM→VMEM tiles, (8,128)/(128,128) MXU/VPU alignment —
 see each kernel's docstring).
 

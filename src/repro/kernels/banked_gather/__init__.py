@@ -8,7 +8,7 @@ from repro.kernels.banked_gather.ref import banked_gather_ref
 from repro.kernels.registry import Kernel, register
 
 
-def _run(arch, table, idx, *, table_banked=False, interpret=True):
+def _run(arch, table, idx, *, table_banked=False):
     """Gather logical rows ``idx`` from a logical table under ``arch``'s
     storage layout (multi-port memories replicate data: no swizzle).
 
@@ -22,7 +22,7 @@ def _run(arch, table, idx, *, table_banked=False, interpret=True):
     if not table_banked:
         table = lay.to_banked(table)
     return banked_gather(table, idx, lay.n_banks, lay.mapping,
-                         shift=lay.shift, interpret=interpret)
+                         shift=lay.shift)
 
 
 register(Kernel(

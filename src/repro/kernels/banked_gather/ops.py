@@ -64,11 +64,10 @@ def banked_gather_trace_blocks(arch, table, idx, mask=None, block_ops=None,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_banks", "mapping", "shift",
-                                    "interpret"))
+                   static_argnames=("n_banks", "mapping", "shift"))
 def banked_gather(table_banked: jnp.ndarray, idx: jnp.ndarray,
-                  n_banks: int = 16, mapping: str = "lsb", shift: int = 1,
-                  interpret: bool = True) -> jnp.ndarray:
+                  n_banks: int = 16, mapping: str = "lsb",
+                  shift: int = 1) -> jnp.ndarray:
     """Gather logical rows `idx` from a bank-major table (see kernel.py)."""
     return banked_gather_kernel(table_banked, idx, n_banks, mapping,
-                                shift=shift, interpret=interpret)
+                                shift=shift)

@@ -6,7 +6,7 @@ from repro.kernels.banked_scatter.ref import banked_scatter_ref
 from repro.kernels.registry import Kernel, register
 
 
-def _run(arch, table, idx, updates, *, table_banked=False, interpret=True):
+def _run(arch, table, idx, updates, *, table_banked=False):
     """Scatter ``updates`` into logical rows ``idx`` of a logical table;
     returns the updated table in logical order.
 
@@ -18,9 +18,9 @@ def _run(arch, table, idx, updates, *, table_banked=False, interpret=True):
         return banked_scatter_ref(table, idx, updates)
     if table_banked:
         return banked_scatter(table, idx, updates, lay.n_banks, lay.mapping,
-                              shift=lay.shift, interpret=interpret)
+                              shift=lay.shift)
     out = banked_scatter(lay.to_banked(table), idx, updates, lay.n_banks,
-                         lay.mapping, shift=lay.shift, interpret=interpret)
+                         lay.mapping, shift=lay.shift)
     return lay.from_banked(out)
 
 

@@ -12,7 +12,11 @@ strided store.  Duplicate indices resolve last-writer-wins in grid order
 the reference for unique indices; duplicate handling is asserted explicitly
 in the tests).
 
-Grid: (n_updates, d_model / D_TILE); block = (1, D_TILE).
+Tables are 2-D ``(V, D)`` or 3-D ``(V, R, D)`` and are viewed as
+``(V, R, D)`` for Mosaic's block tiling, exactly as in banked_gather (see
+its module docstring).
+
+Grid: (n_updates, D / D_TILE); block = (None, R, D_TILE).
 
 Caveat (documented): Pallas requires every output block to be written each
 grid step; rows NOT touched by any index keep their prior contents because
@@ -25,9 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.banked_gather.kernel import _bank_physical_row, _row_tile
-
-D_TILE = 512
+from repro.core.arch import physical_row_of
+from repro.kernels import registry
+from repro.kernels.banked_gather.kernel import _row_tile, as_rows, row_block
 
 
 def _scatter_kernel(idx_ref, updates_ref, table_ref, out_ref):
@@ -37,37 +41,38 @@ def _scatter_kernel(idx_ref, updates_ref, table_ref, out_ref):
 
 def banked_scatter_kernel(table_banked: jax.Array, idx: jax.Array,
                           updates: jax.Array, n_banks: int,
-                          mapping: str = "lsb", shift: int = 1,
-                          interpret: bool = True) -> jax.Array:
-    """Write updates[i] to logical row idx[i] of a bank-major table."""
-    v, d = table_banked.shape
+                          mapping: str = "lsb", shift: int = 1) -> jax.Array:
+    """Write updates[i] to logical row idx[i] of a bank-major table
+    (updates: (N,) + the table's row shape)."""
+    rows = as_rows(table_banked)
+    v, r, d = rows.shape
     n = idx.shape[0]
-    assert updates.shape == (n, d)
+    assert updates.shape == (n,) + table_banked.shape[1:]
     assert v % n_banks == 0, (v, n_banks)
     d_tile = _row_tile(d)
-    log2b = n_banks.bit_length() - 1
     rows_per_bank = v // n_banks
 
     def upd_map(i, j, idx_ref):
-        return (i, j)
+        return (i, 0, j)
 
     def out_map(i, j, idx_ref):
-        phys = _bank_physical_row(idx_ref[i], n_banks, log2b, rows_per_bank,
-                                  mapping, shift)
-        return (phys, j)
+        phys = physical_row_of(idx_ref[i], n_banks, rows_per_bank, mapping,
+                               shift)
+        return (phys, 0, j)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n, d // d_tile),
-        in_specs=[pl.BlockSpec((1, d_tile), upd_map),
-                  pl.BlockSpec((1, d_tile), out_map)],
-        out_specs=pl.BlockSpec((1, d_tile), out_map),
+        in_specs=[pl.BlockSpec(row_block(rows), upd_map),
+                  pl.BlockSpec(row_block(rows), out_map)],
+        out_specs=pl.BlockSpec(row_block(rows), out_map),
     )
     fn = pl.pallas_call(
         _scatter_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((v, d), table_banked.dtype),
+        out_shape=jax.ShapeDtypeStruct((v, r, d), table_banked.dtype),
         input_output_aliases={2: 0},   # donate the table (arg 1 after idx)
-        interpret=interpret,
+        interpret=registry.interpret_mode(),
     )
-    return fn(idx.astype(jnp.int32), updates, table_banked)
+    out = fn(idx.astype(jnp.int32), as_rows(updates), rows)
+    return out.reshape(table_banked.shape)

@@ -35,13 +35,11 @@ def banked_scatter_trace_blocks(arch, table, idx, updates=None, mask=None,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_banks", "mapping", "shift",
-                                    "interpret"))
+                   static_argnames=("n_banks", "mapping", "shift"))
 def banked_scatter(table_banked: jnp.ndarray, idx: jnp.ndarray,
                    updates: jnp.ndarray, n_banks: int = 16,
-                   mapping: str = "lsb", shift: int = 1,
-                   interpret: bool = True) -> jnp.ndarray:
+                   mapping: str = "lsb", shift: int = 1) -> jnp.ndarray:
     """Scatter update rows into logical rows `idx` of a bank-major table
     (see kernel.py; pairs with banked_gather for the paged-KV write path)."""
     return banked_scatter_kernel(table_banked, idx, updates, n_banks,
-                                 mapping, shift=shift, interpret=interpret)
+                                 mapping, shift=shift)

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import registry
+
 TILE = 128
 
 
@@ -23,8 +25,7 @@ def _transpose_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...].T
 
 
-def banked_transpose_kernel(x: jax.Array, tile: int = TILE,
-                            interpret: bool = True):
+def banked_transpose_kernel(x: jax.Array, tile: int = TILE):
     n, m = x.shape
     t = min(tile, n, m)
     assert n % t == 0 and m % t == 0, (n, m, t)
@@ -34,5 +35,5 @@ def banked_transpose_kernel(x: jax.Array, tile: int = TILE,
         in_specs=[pl.BlockSpec((t, t), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((t, t), lambda i, j: (j, i)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        interpret=interpret,
+        interpret=registry.interpret_mode(),
     )(x)

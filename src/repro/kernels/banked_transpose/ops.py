@@ -51,8 +51,7 @@ def banked_transpose_trace_blocks(arch, x, block_ops=None, **_):
                                   block_ops=block_ops)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def banked_transpose(x: jnp.ndarray, tile: int = 128,
-                     interpret: bool = True) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("tile",))
+def banked_transpose(x: jnp.ndarray, tile: int = 128) -> jnp.ndarray:
     """(N, M) -> (M, N) via VMEM-tiled transpose."""
-    return banked_transpose_kernel(x, tile=tile, interpret=interpret)
+    return banked_transpose_kernel(x, tile=tile)

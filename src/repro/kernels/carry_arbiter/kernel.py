@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import registry
+
 OP_BLOCK = 128
 MAX_CYCLES = 16
 
@@ -34,7 +36,7 @@ def _arbiter_kernel(req_ref, grants_ref):
     # all requests must drain within MAX_CYCLES (≤ lanes); v == 0 here.
 
 
-def carry_arbiter_kernel(requests: jax.Array, interpret: bool = True):
+def carry_arbiter_kernel(requests: jax.Array):
     n_ops, n_banks = requests.shape
     blk = min(OP_BLOCK, n_ops)
     assert n_ops % blk == 0
@@ -46,5 +48,5 @@ def carry_arbiter_kernel(requests: jax.Array, interpret: bool = True):
                                lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_ops, MAX_CYCLES, n_banks),
                                        jnp.uint32),
-        interpret=interpret,
+        interpret=registry.interpret_mode(),
     )(requests.astype(jnp.uint32))

@@ -65,8 +65,8 @@ def carry_arbiter_trace_blocks(arch, requests, block_ops=None, **_):
         yield blk
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def carry_arbiter(requests: jnp.ndarray, interpret: bool = True):
+@jax.jit
+def carry_arbiter(requests: jnp.ndarray):
     """(ops, B) packed uint32 lane-request words -> (ops, 16, B) one-hot
     grant schedule (cycle-major), bit-exact vs the scan reference."""
-    return carry_arbiter_kernel(requests, interpret=interpret)
+    return carry_arbiter_kernel(requests)

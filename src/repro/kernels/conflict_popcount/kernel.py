@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import registry
+
 OP_BLOCK = 256
 LANES = 16
 
@@ -33,8 +35,7 @@ def _popcount_kernel(n_banks: int, banks_ref, counts_ref, cycles_ref):
     cycles_ref[...] = counts.max(axis=1, keepdims=True)     # (BLK, 1)
 
 
-def conflict_popcount_kernel(banks: jax.Array, n_banks: int,
-                             interpret: bool = True):
+def conflict_popcount_kernel(banks: jax.Array, n_banks: int):
     n_ops, lanes = banks.shape
     assert lanes == LANES and n_ops % OP_BLOCK == 0 or n_ops < OP_BLOCK
     blk = min(OP_BLOCK, n_ops)
@@ -48,6 +49,6 @@ def conflict_popcount_kernel(banks: jax.Array, n_banks: int,
                    pl.BlockSpec((blk, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((n_ops, n_banks), jnp.int32),
                    jax.ShapeDtypeStruct((n_ops, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=registry.interpret_mode(),
     )(banks.astype(jnp.int32))
     return counts, cycles[:, 0]

@@ -36,8 +36,7 @@ def conflict_popcount_trace_blocks(arch, banks, n_banks=None, block_ops=None,
     yield from iter_op_chunks(banks, kind="load", block_ops=block_ops)
 
 
-@functools.partial(jax.jit, static_argnames=("n_banks", "interpret"))
-def conflict_popcount(banks: jnp.ndarray, n_banks: int = 16,
-                      interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("n_banks",))
+def conflict_popcount(banks: jnp.ndarray, n_banks: int = 16):
     """(ops, 16) lane bank ids -> ((ops, B) counts, (ops,) max cycles)."""
-    return conflict_popcount_kernel(banks, n_banks, interpret=interpret)
+    return conflict_popcount_kernel(banks, n_banks)

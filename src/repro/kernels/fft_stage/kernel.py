@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import registry
+
 ROW_BLOCK = 128
 
 
@@ -46,7 +48,7 @@ def _stage_kernel(xr_ref, xi_ref, twr_ref, twi_ref, or_ref, oi_ref):
 
 
 def fft_stage_kernel(xr: jax.Array, xi: jax.Array, twr: jax.Array,
-                     twi: jax.Array, interpret: bool = True):
+                     twi: jax.Array):
     rows, radix, sub = xr.shape
     assert radix == 4 and twr.shape == (1, 4, sub)
     blk = min(ROW_BLOCK, rows)
@@ -62,5 +64,5 @@ def fft_stage_kernel(xr: jax.Array, xi: jax.Array, twr: jax.Array,
                    pl.BlockSpec((blk, 4, sub), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(xr.shape, jnp.float32),
                    jax.ShapeDtypeStruct(xi.shape, jnp.float32)],
-        interpret=interpret,
+        interpret=registry.interpret_mode(),
     )(xr, xi, twr, twi)

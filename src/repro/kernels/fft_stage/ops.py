@@ -20,9 +20,8 @@ def _stage_twiddles(n: int, p: int) -> tuple[np.ndarray, np.ndarray]:
     return (tw.real[None], tw.imag[None])  # (1, 4, sub)
 
 
-@functools.partial(jax.jit, static_argnames=("n", "p", "interpret"))
-def fft_stage_radix4(xr: jnp.ndarray, xi: jnp.ndarray, n: int, p: int,
-                     interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("n", "p"))
+def fft_stage_radix4(xr: jnp.ndarray, xi: jnp.ndarray, n: int, p: int):
     """Apply DIF pass p of a radix-4 size-n FFT to (batch, n) planes."""
     batch = xr.shape[0]
     m = n // 4 ** p
@@ -31,8 +30,7 @@ def fft_stage_radix4(xr: jnp.ndarray, xi: jnp.ndarray, n: int, p: int,
     view = lambda t: t.reshape(batch * (n // m), 4, sub)
     rows = batch * (n // m)
     yr, yi = fft_stage_kernel(view(xr), view(xi),
-                              jnp.asarray(twr), jnp.asarray(twi),
-                              interpret=interpret)
+                              jnp.asarray(twr), jnp.asarray(twi))
     return yr.reshape(batch, n), yi.reshape(batch, n)
 
 
@@ -76,13 +74,12 @@ def fft_trace_blocks(arch, x, block_ops=None, **_):
                                   block_ops=block_ops)
 
 
-def fft4096_radix4(x: jnp.ndarray, n: int = 4096,
-                   interpret: bool = True) -> jnp.ndarray:
+def fft4096_radix4(x: jnp.ndarray, n: int = 4096) -> jnp.ndarray:
     """(batch, n) complex64 -> FFT in digit-reversed order (batch, n)."""
     xr = jnp.real(x).astype(jnp.float32)
     xi = jnp.imag(x).astype(jnp.float32)
     passes = int(round(np.log(n) / np.log(4)))
     assert 4 ** passes == n
     for p in range(passes):
-        xr, xi = fft_stage_radix4(xr, xi, n, p, interpret=interpret)
+        xr, xi = fft_stage_radix4(xr, xi, n, p)
     return (xr + 1j * xi).astype(jnp.complex64)

@@ -35,10 +35,9 @@ def moe_dispatch_trace_blocks(arch, experts, n_experts, capacity,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_experts", "capacity", "interpret"))
+                   static_argnames=("n_experts", "capacity"))
 def moe_dispatch_positions(experts: jnp.ndarray, n_experts: int,
-                           capacity: int, interpret: bool = True):
+                           capacity: int):
     """(R,) flat priority-ordered expert ids -> ((R,) position-in-expert,
     (R,) kept mask) under the capacity budget."""
-    return moe_dispatch_kernel(experts, n_experts, capacity,
-                               interpret=interpret)
+    return moe_dispatch_kernel(experts, n_experts, capacity)

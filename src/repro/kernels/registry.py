@@ -41,7 +41,17 @@ import functools
 from dataclasses import dataclass
 from typing import Callable
 
+import jax
+
 from repro.core import arch as _arch
+
+
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in the interpreter: never on a TPU,
+    where the compiled kernel is what serving and the benchmarks run, and
+    always elsewhere (the CPU, where the tests run).  Every ``pallas_call``
+    in ``repro.kernels`` asks this one function; there is no option."""
+    return jax.default_backend() != "tpu"
 
 
 @dataclass(frozen=True)

@@ -50,8 +50,6 @@ def _analyze(compiled) -> dict:
     out = {}
     try:
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # jax <= 0.4.x: one dict/program
-            ca = ca[0] if ca else {}
         out["flops"] = float(ca.get("flops", 0.0))
         out["bytes_accessed"] = float(ca.get("bytes accessed", 0.0))
     except Exception as e:  # pragma: no cover

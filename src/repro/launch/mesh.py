@@ -5,26 +5,17 @@ xla_force_host_platform_device_count *before* any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 SINGLE_POD = (16, 16)                 # 256 chips/pod (v5e pod slice)
 MULTI_POD = (2, 16, 16)               # 2 pods = 512 chips
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across the 0.4.x → 0.5+ AxisType drift: newer jax
-    wants explicit ``axis_types`` (we always mean Auto); jax 0.4.37 has
-    neither the kwarg nor ``jax.sharding.AxisType``, and Auto is its only
-    behavior — so the kwarg is simply omitted there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kw = ({"axis_types": (axis_type.Auto,) * len(axes)} if axis_type
-          else {})
-    return jax.make_mesh(shape, axes, **kw)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def chips(mesh) -> int:

@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_smoke_config
 from repro.configs.base import RunConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.sharding import NO_AXES
 from repro.models import init_tree, model_specs
 from repro.serving.engine import ServeEngine
@@ -161,6 +162,7 @@ def main():
         ap.error("--schedule needs --kv-mode paged (continuous batching "
                  "lives on the banked page pool)")
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch)
     rc = RunConfig(remat="none", attn_impl="dense")
     params = init_tree(model_specs(cfg), jax.random.PRNGKey(0))

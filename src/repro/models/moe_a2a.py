@@ -24,6 +24,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -31,18 +32,11 @@ from repro.core.arbiter import grant_positions
 from repro.launch.sharding import Axes
 from repro.models.moe import capacity
 
-try:
-    from jax import shard_map as _shard_map
 
-    def _smap(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+def _smap(f, mesh, in_specs, out_specs):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=False)
 
-    def _smap(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
 
 Array = jnp.ndarray
 

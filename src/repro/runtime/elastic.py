@@ -12,9 +12,9 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 from repro.checkpoint.checkpoint import latest_step, restore_checkpoint
-from repro.launch.mesh import compat_make_mesh
 
 
 def _largest_pow2_factor(n: int) -> int:
@@ -36,7 +36,8 @@ def make_current_mesh(prefer_model: int = 0):
         while model * 2 <= math.isqrt(n) and n % (model * 2) == 0:
             model *= 2
     data = n // model
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def elastic_restore(ckpt_dir: str, template_state):

@@ -66,7 +66,7 @@ class ServeEngine:
     def __init__(self, cfg: ModelConfig, rc: RunConfig, params, ax: Axes,
                  max_batch: int = 8, max_seq: int = 256,
                  mem_arch="16B", kv_mode: str = "paged",
-                 page_len: int = 8, kernel_interpret: bool = True):
+                 page_len: int = 8):
         self.cfg, self.rc, self.ax = cfg, rc, ax
         self.params = params
         self.max_batch, self.max_seq = max_batch, max_seq
@@ -82,7 +82,6 @@ class ServeEngine:
                 f"mem_arch for paged-KV serving (or kv_mode='dense')")
         self.kv_mode = kv_mode
         self.page_len = page_len
-        self.kernel_interpret = kernel_interpret
         self.kv_cfg = (self.paged_kv_config(page_len)
                        if kv_mode == "paged" else None)
         self._prefill = jax.jit(
@@ -143,10 +142,8 @@ class ServeEngine:
         kvh, hd = cfg.n_kv_heads, cfg.hd
         q, k_new, v_new = L._qkv(cfg, p, x, pos[None], ax)
         ids = jnp.maximum(pages.page_table, 0).reshape(-1)
-        ck = KV.gather_pages(arch, kv, cache["k"], ids,
-                             interpret=self.kernel_interpret)
-        cv = KV.gather_pages(arch, kv, cache["v"], ids,
-                             interpret=self.kernel_interpret)
+        ck = KV.gather_pages(arch, kv, cache["k"], ids)
+        cv = KV.gather_pages(arch, kv, cache["v"], ids)
         ck = ck.reshape(b, s_all, kvh, hd)
         cv = cv.reshape(b, s_all, kvh, hd)
         hot = (jnp.arange(s_all) == pos)[None, :, None, None]
@@ -171,11 +168,9 @@ class ServeEngine:
         k_line = jax.lax.dynamic_slice_in_dim(ck, pg * plen, plen, axis=1)
         v_line = jax.lax.dynamic_slice_in_dim(cv, pg * plen, plen, axis=1)
         kp = KV.scatter_pages(arch, kv, cache["k"], cur,
-                              k_line.reshape(b, -1),
-                              interpret=self.kernel_interpret)
+                              k_line.reshape((b,) + kv.page_shape))
         vp = KV.scatter_pages(arch, kv, cache["v"], cur,
-                              v_line.reshape(b, -1),
-                              interpret=self.kernel_interpret)
+                              v_line.reshape((b,) + kv.page_shape))
         return out, {"k": kp, "v": vp}
 
     def _paged_step(self, params, tok, pools, pages, ssm, pos):
@@ -236,10 +231,9 @@ class ServeEngine:
             buf = jnp.zeros((batch, n_pref * plen_pg) + kc.shape[2:],
                             kc.dtype)
             buf = buf.at[:, plen - t:plen].set(kc)
-            rows = buf.reshape(batch * n_pref, kv.row_width)
-            pool2d = jnp.zeros((kv.n_pages, kv.row_width), kc.dtype)
-            return KV.scatter_pages(self.mem_arch, kv, pool2d, ids, rows,
-                                    interpret=self.kernel_interpret)
+            rows = buf.reshape((batch * n_pref,) + kv.page_shape)
+            pool = jnp.zeros((kv.n_pages,) + kv.page_shape, kc.dtype)
+            return KV.scatter_pages(self.mem_arch, kv, pool, ids, rows)
 
         pools, ssm = {}, {}
         for j, (kind, _) in enumerate(self.cfg.block_pattern()):
@@ -273,10 +267,8 @@ class ServeEngine:
         kvh, hd = cfg.n_kv_heads, cfg.hd
         q, k_new, v_new = L._qkv(cfg, p, x, pos[:, None], ax)
         ids = jnp.maximum(page_table, 0).reshape(-1)
-        ck = KV.gather_pages(arch, kv, cache["k"], ids,
-                             interpret=self.kernel_interpret)
-        cv = KV.gather_pages(arch, kv, cache["v"], ids,
-                             interpret=self.kernel_interpret)
+        ck = KV.gather_pages(arch, kv, cache["k"], ids)
+        cv = KV.gather_pages(arch, kv, cache["v"], ids)
         ck = ck.reshape(b, s_all, kvh, hd)
         cv = cv.reshape(b, s_all, kvh, hd)
         idx = jnp.arange(s_all)
@@ -305,11 +297,9 @@ class ServeEngine:
         k_line = jnp.take_along_axis(ck, line[:, :, None, None], axis=1)
         v_line = jnp.take_along_axis(cv, line[:, :, None, None], axis=1)
         kp = KV.scatter_pages(arch, kv, cache["k"], cur,
-                              k_line.reshape(b, -1),
-                              interpret=self.kernel_interpret)
+                              k_line.reshape((b,) + kv.page_shape))
         vp = KV.scatter_pages(arch, kv, cache["v"], cur,
-                              v_line.reshape(b, -1),
-                              interpret=self.kernel_interpret)
+                              v_line.reshape((b,) + kv.page_shape))
         return out, {"k": kp, "v": vp}
 
     def _scheduler_step(self, params, tok, pools, page_table, pos, active,
@@ -342,7 +332,7 @@ class ServeEngine:
     def _prefill_rows(self, prompt: np.ndarray):
         """Prefill ONE request and lower its K/V to page rows: returns the
         request's first generated token id and a per-pool dict of
-        ``(n_pref, row_width)`` row arrays — page ``k``'s row at index
+        ``(n_pref,) + page_shape`` page arrays — page ``k`` at index
         ``k``, ready to scatter at whatever tick the scheduler lands that
         page (whole-prompt admission scatters all rows at once; chunked
         prefill scatters slices as ``ev.prefill_chunks`` records arrive).
@@ -362,7 +352,7 @@ class ServeEngine:
             buf = jnp.zeros((1, n_pref * kv.page_len) + kc.shape[2:],
                             kc.dtype)
             buf = buf.at[:, plen - t:plen].set(kc)
-            return buf.reshape(n_pref, kv.row_width)
+            return buf.reshape((n_pref,) + kv.page_shape)
 
         rows = {}
         for j, (kind, _) in enumerate(self.cfg.block_pattern()):
@@ -384,8 +374,7 @@ class ServeEngine:
             pools[key] = {
                 h: KV.scatter_pages(
                     self.mem_arch, self.kv_cfg, pools[key][h], ids,
-                    pair[h][page_start:page_start + n],
-                    interpret=self.kernel_interpret)
+                    pair[h][page_start:page_start + n])
                 for h in ("k", "v")}
         return pools
 
@@ -408,11 +397,9 @@ class ServeEngine:
         for key, pair in pools.items():
             out = {}
             for half in ("k", "v"):
-                rows = KV.gather_pages(self.mem_arch, kv, pair[half], old,
-                                       interpret=self.kernel_interpret)
+                rows = KV.gather_pages(self.mem_arch, kv, pair[half], old)
                 out[half] = KV.scatter_pages(self.mem_arch, kv, pair[half],
-                                             new, rows,
-                                             interpret=self.kernel_interpret)
+                                             new, rows)
             pools[key] = out
         return pools
 
@@ -510,8 +497,8 @@ class ServeEngine:
         pools = {}
         for j, (kind, _) in enumerate(self.cfg.block_pattern()):
             for sb in range(self.cfg.n_superblocks):
-                zero = jnp.zeros((self.kv_cfg.n_pages, self.kv_cfg.row_width),
-                                 dtype)
+                zero = jnp.zeros((self.kv_cfg.n_pages,)
+                                 + self.kv_cfg.page_shape, dtype)
                 pools[f"b{j}s{sb}"] = {"k": zero, "v": zero}
         scratch = jnp.asarray(sched.scratch_page or 0, jnp.int32)
         lane_tok = jnp.zeros((self.max_batch, 1), jnp.int32)

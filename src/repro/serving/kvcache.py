@@ -22,7 +22,7 @@ and requests beyond a bank's free capacity spill to the least-loaded bank
 Three access paths share the layout:
 
   * kernel path (the serving hot path): ``gather_pages`` / ``scatter_pages``
-    call the registry kernels on a persistent bank-major 2-D pool
+    call the registry kernels on a persistent bank-major page-slab pool
     (``table_banked=True`` — no per-call relayout);
   * reference path: ``append_token`` / ``gather_kv`` are the pure-jnp oracle
     on a 4-D pool, used by tests to pin the kernel path bit-exactly;
@@ -152,9 +152,12 @@ class PagedKVConfig:
         return self.n_pages // self.n_banks
 
     @property
-    def row_width(self) -> int:
-        """Words per page line in the 2-D kernel view of the pool."""
-        return self.page_len * self.kv_heads * self.head_dim
+    def page_shape(self) -> tuple[int, int]:
+        """One page of a serving pool: ``page_len`` token rows of
+        ``kv_heads·head_dim`` words.  Pools are ``(n_pages,) + page_shape``,
+        the banked kernels' row-slab view, which a TPU kernel reads in the
+        array's default layout (no relayout copy per call)."""
+        return (self.page_len, self.kv_heads * self.head_dim)
 
 
 class PageTableState(NamedTuple):
@@ -334,28 +337,28 @@ def bank_load_stats(state) -> dict:
 # --------------------------------------------------------------------------
 
 def pool_rows(pool: Array) -> Array:
-    """(n_pages, L, KV, HD) pool -> (n_pages, L·KV·HD) kernel view (one page
+    """(n_pages, L, KV, HD) pool -> (n_pages, L, KV·HD) kernel view (one page
     = one bank-major table row)."""
-    return pool.reshape(pool.shape[0], -1)
+    return pool.reshape(pool.shape[:2] + (-1,))
 
 
-def gather_pages(arch, cfg: PagedKVConfig, pool2d: Array,
-                 page_ids: Array, interpret: bool = True) -> Array:
+def gather_pages(arch, cfg: PagedKVConfig, pool: Array,
+                 page_ids: Array) -> Array:
     """Gather page lines by *logical* pool page id through
     ``kernels.get("banked_gather")`` (bank-major persistent pool — no
     relayout).  page_ids: (N,) int32, already clamped ≥ 0."""
     from repro.kernels import registry
     return registry.get("banked_gather").run(
-        arch, pool2d, page_ids, table_banked=True, interpret=interpret)
+        arch, pool, page_ids, table_banked=True)
 
 
-def scatter_pages(arch, cfg: PagedKVConfig, pool2d: Array, page_ids: Array,
-                  rows: Array, interpret: bool = True) -> Array:
+def scatter_pages(arch, cfg: PagedKVConfig, pool: Array, page_ids: Array,
+                  rows: Array) -> Array:
     """Scatter page lines into *logical* pool page ids through
     ``kernels.get("banked_scatter")``; returns the updated bank-major pool."""
     from repro.kernels import registry
     return registry.get("banked_scatter").run(
-        arch, pool2d, page_ids, rows, table_banked=True, interpret=interpret)
+        arch, pool, page_ids, rows, table_banked=True)
 
 
 # --------------------------------------------------------------------------

@@ -23,20 +23,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_rep):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep)
+def shard_map(f, mesh, in_specs, out_specs, check_rep):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=check_rep)
 
 
 def pipelined(stage_fn, mesh, axis_name: str = "stage"):

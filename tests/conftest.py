@@ -7,15 +7,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# The property tests use hypothesis; the container may not ship it.  Fall
-# back to the deterministic stub (no pip installs at test time).
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub
-    _hypothesis_stub.install()
-
 import pytest
 
 

@@ -134,16 +134,16 @@ def test_banked_layout_roundtrip_property(n_banks, mapping):
 @pytest.mark.parametrize("mapping", ["lsb", "offset", "xor"])
 def test_banked_layout_matches_kernel_physical_rows(n_banks, mapping):
     """The single source of truth agrees with the gather/scatter kernels'
-    internal index-map math (which now delegates to it) and with the legacy
-    ops.py helpers."""
-    from repro.kernels.banked_gather.kernel import _bank_physical_row
+    index-map math (``physical_row_of``, called inside their index maps)
+    and with the legacy ops.py helpers."""
+    from repro.core.arch import physical_row_of
     from repro.kernels.banked_gather.ops import physical_rows
     n_rows = 256
     lay = BankedLayout(n_banks, mapping)
     want = np.asarray(lay.physical_rows(n_rows))
     r = jnp.arange(n_rows, dtype=jnp.int32)
-    got_kernel = np.asarray(_bank_physical_row(
-        r, n_banks, n_banks.bit_length() - 1, n_rows // n_banks, mapping))
+    got_kernel = np.asarray(physical_row_of(
+        r, n_banks, n_rows // n_banks, mapping))
     np.testing.assert_array_equal(want, got_kernel)
     np.testing.assert_array_equal(
         want, np.asarray(physical_rows(n_rows, n_banks, mapping)))

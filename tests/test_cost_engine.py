@@ -363,7 +363,7 @@ def test_arch_cost_auto_streams_above_threshold():
 
 # ------------------------------------------------ (c) property testing --
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(st.integers(1, 600), st.integers(0, 2 ** 20), st.integers(0, 1),
        st.sampled_from([1, 3, 16, 1000]))
 def test_property_op_chunk_streams_equal_dense(n_req, seed, masked,
@@ -382,7 +382,7 @@ def test_property_op_chunk_streams_equal_dense(n_req, seed, masked,
     assert cost_many(archs, stream) == cost_many(archs, dense)
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(st.integers(1, 40), st.integers(0, 2 ** 20), st.integers(0, 3),
        st.sampled_from([1, 7, 16, 1000]))
 def test_property_random_traces_engine_equals_loop(n_ops, seed, mask_mode,

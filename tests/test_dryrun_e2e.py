@@ -19,8 +19,9 @@ import jax
 from repro.configs import SHAPES, get_smoke_config
 from repro.launch.dryrun import lower_cell
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 import dataclasses
 shape = dataclasses.replace(SHAPES["%(shape)s"], global_batch=8, seq_len=64)
 res = lower_cell("%(arch)s", shape, multi_pod=False, verbose=False,

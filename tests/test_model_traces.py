@@ -216,7 +216,7 @@ def test_whole_step_winner_flips_attention_kernel_winner():
 
 # ------------------------------------------------------ property testing --
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(st.integers(4, 64), st.integers(1, 8),
        st.sampled_from([4, 8, 16]), st.integers(0, 2 ** 20))
 def test_property_attn_decode_engine_equals_loop(seq_len, n_heads,
@@ -246,7 +246,7 @@ def test_property_attn_decode_engine_equals_loop(seq_len, n_heads,
         assert c == a._cost_loop(t), a.name
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(st.sampled_from([2, 4, 8]), st.integers(1, 96),
        st.integers(0, 2 ** 20))
 def test_property_moe_a2a_engine_equals_loop(n_experts, n_req, seed):

@@ -23,8 +23,9 @@ from repro.models.moe_a2a import a2a_applicable, moe_a2a
 cfg = dataclasses.replace(get_smoke_config("phi3.5-moe-42b-a6.6b"),
                           n_experts=%(experts)d, experts_per_token=%(k)d,
                           capacity_factor=16.0)  # no drops
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 2), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 ax = make_axes(mesh, None)
 params = init_tree(moe_specs(cfg), jax.random.PRNGKey(0))
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),

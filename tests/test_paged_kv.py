@@ -127,7 +127,7 @@ def test_from_arch_pool_allocates_and_roundtrips():
 
 @pytest.mark.parametrize("arch_name", ["8B-xor", "16B-offset", "4B"])
 def test_kernel_gather_matches_reference_bitexact(arch_name):
-    """The serving hot path (banked_gather on the bank-major 2-D pool view)
+    """The serving hot path (banked_gather on the bank-major page-slab view)
     returns bit-identical page lines to the reference 4-D pool — across
     page boundaries and for every bank map."""
     from repro.core import arch as A
@@ -160,8 +160,8 @@ def test_kernel_scatter_then_gather_roundtrip():
     pages = init_pages(cfg, batch=4, max_seq=8)
     pages, ids = allocate_pages(cfg, pages, jnp.ones((4,), bool))
     rows = jnp.asarray(np.random.default_rng(3).standard_normal(
-        (4, cfg.row_width)), jnp.float32)
-    pool = jnp.zeros((cfg.n_pages, cfg.row_width), jnp.float32)
+        (4,) + cfg.page_shape), jnp.float32)
+    pool = jnp.zeros((cfg.n_pages,) + cfg.page_shape, jnp.float32)
     pool = scatter_pages(a, cfg, pool, ids, rows)
     back = gather_pages(a, cfg, pool, ids)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(rows))

@@ -15,8 +15,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np, json
 from repro.train.pipeline import pipelined, stack_stage_params
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((4,), ("stage",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
 D = 16
 key = jax.random.PRNGKey(0)
 stages = []

@@ -3,6 +3,7 @@ regression values (the faithful-reproduction gate)."""
 import numpy as np
 import pytest
 
+from benchmarks.paper_data import TABLE2_EXACT
 from repro.core.memsim import PAPER_MEMORIES, banked, multiport
 from repro.isa.programs.fft import (digit_reverse_indices, fft_program,
                                     make_fft_memory, oracle_spectrum)
@@ -40,11 +41,7 @@ def test_transpose_functional(n):
 
 # --- Table II regression (paper values; cycle-exact cells asserted hard) ----
 
-TABLE2 = {  # n -> mem -> (load, store)
-    32: {"16B": (168, 1054), "4R-1W": (256, 1024), "4R-2W": (256, 512)},
-    64: {"16B": (1184, 4216), "4R-1W": (1024, 4096)},
-    128: {"16B": (8832, 16864), "4R-1W": (4096, 16384)},
-}
+TABLE2 = TABLE2_EXACT  # n -> mem -> (load, store)
 
 
 @pytest.mark.parametrize("n", [32, 64, 128])

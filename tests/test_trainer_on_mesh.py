@@ -27,14 +27,16 @@ ds = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=32, global_batch=8,
                  seed=1, branching=2)
 ck = os.path.join("%(tmp)s", "ck")
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 2), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 tc = TrainerConfig(total_steps=8, ckpt_dir=ck, ckpt_every=4, log_every=2)
 out = Trainer(cfg, rc, tc, ds, mesh=mesh).run()
 loss_mesh = out["final"]["loss"]
 
 # elastic: restore the (2,2)-trained checkpoint onto a (4,1) mesh
-mesh2 = compat_make_mesh((4, 1), ("data", "model"))
+mesh2 = jax.make_mesh((4, 1), ("data", "model"),
+                      axis_types=(AxisType.Auto,) * 2)
 template = init_train_state(cfg, rc, jax.random.PRNGKey(0))
 state, step = elastic_restore(ck, template)
 print("RESULT " + json.dumps({
