@@ -80,6 +80,7 @@ from repro.core.conflicts import first_occurrence
 from repro.core.memsim import LANES, MemSpec, TraceCost
 from repro.core.trace import (KIND_LOAD, KIND_STORE, KIND_TW, AddressTrace,
                               TraceStream, as_trace)
+from repro.runtime import telemetry
 
 __all__ = ["cost_many", "lower_archs", "ArchTable", "BlockCostCache",
            "DEFAULT_BLOCK_OPS", "STREAM_THRESHOLD"]
@@ -546,12 +547,26 @@ _FOLD_EVERY = 256
 
 
 def _fold(totals, partials: list, n_archs: int) -> np.ndarray:
-    if totals is None:
-        totals = np.zeros((n_archs, 3), np.int64)
-    for p in partials:
-        totals += np.asarray(p, np.int64)
-    partials.clear()
+    with telemetry.span("cost.fold"):
+        if totals is None:
+            totals = np.zeros((n_archs, 3), np.int64)
+        for p in partials:
+            totals += np.asarray(p, np.int64)
+        partials.clear()
     return totals
+
+
+def _timed_blocks(blocks):
+    """``blocks`` with the time spent pulling each one from the stream
+    (its construction, for a lazy stream) recorded as ``cost.blocks``."""
+    it = iter(blocks)
+    end = object()
+    while True:
+        with telemetry.span("cost.blocks"):
+            blk = next(it, end)
+        if blk is end:
+            return
+        yield blk
 
 
 class _InstrCounter:
@@ -631,17 +646,56 @@ def cost_many(archs, trace, block_ops: int | None = None,
     if not arch_objs:
         return []
     table = _lowered(tuple(a.spec for a in arch_objs))
+    with telemetry.span("cost.many"):
+        totals, counter, compute_cycles, op_counts = _cost_stream(
+            table, trace, block_ops, checked, prefetch, cache)
+    n_instr, n_ops = counter.n_instr, counter.n_ops
+
+    costs = []
+    for i in range(len(table)):
+        r_ovh, w_ovh = (int(table.overheads[i, 0]),
+                        int(table.overheads[i, 1]))
+        kind_cycles = {
+            KIND_LOAD: int(totals[i, 0]) + int(n_instr[0]) * r_ovh,
+            KIND_STORE: int(totals[i, 1]) + int(n_instr[1]) * w_ovh,
+            KIND_TW: int(totals[i, 2]) + int(n_instr[2]) * r_ovh,
+        }
+        costs.append(TraceCost(
+            load_cycles=kind_cycles[KIND_LOAD] if n_ops[0] else 0,
+            store_cycles=kind_cycles[KIND_STORE] if n_ops[1] else 0,
+            tw_load_cycles=kind_cycles[KIND_TW] if n_ops[2] else 0,
+            compute_cycles=int(compute_cycles),
+            n_load_ops=int(n_ops[0]), n_store_ops=int(n_ops[1]),
+            n_tw_ops=int(n_ops[2]),
+            fp_ops=int(op_counts.get("fp", 0)),
+            int_ops=int(op_counts.get("int", 0)),
+            imm_ops=int(op_counts.get("imm", 0)),
+            other_ops=int(op_counts.get("other", 0))))
+    return costs
+
+
+def _cost_stream(table: ArchTable, trace, block_ops, checked, prefetch,
+                 cache):
+    """``cost_many``'s one pass over the trace: the int64 (n_archs, 3)
+    conflict cycles, the instruction counter, and the compute cycles and
+    op counts the blocks carry."""
     params = jnp.asarray(table.params)
     remaps = jnp.asarray(table.remaps)
-    n_archs = len(arch_objs)
+    n_archs = len(table)
 
     def _dispatch(addrs, mask, kinds):
-        addrs, mask, kinds = _pad_ops(addrs, mask, kinds)
-        return _block_kind_cycles(
-            params, remaps, jnp.asarray(addrs), jnp.asarray(mask),
-            jnp.asarray(kinds), need_uniq=table.need_uniq,
-            need_remap=table.need_remap, need_mod=table.need_mod,
-            need_two_level=table.need_two_level)
+        telemetry.count("cost.ops", addrs.shape[0])
+        with telemetry.span("cost.pad"):
+            addrs, mask, kinds = _pad_ops(addrs, mask, kinds)
+        telemetry.count("cost.padded_ops", addrs.shape[0])
+        with telemetry.span("cost.transfer"):
+            args = (jnp.asarray(addrs), jnp.asarray(mask),
+                    jnp.asarray(kinds))
+        with telemetry.span("cost.dispatch"):
+            return _block_kind_cycles(
+                params, remaps, *args, need_uniq=table.need_uniq,
+                need_remap=table.need_remap, need_mod=table.need_mod,
+                need_two_level=table.need_two_level)
 
     totals = None
     counter = _InstrCounter()
@@ -666,6 +720,7 @@ def cost_many(archs, trace, block_ops: int | None = None,
                        else None)
             blocks = _contracts.checked_blocks(blocks, n_words=n_words,
                                                where="cost_many(checked)")
+    blocks = _timed_blocks(blocks)
 
     if cache is not None:
         # block-at-a-time with content-addressed memoization: hits add
@@ -676,11 +731,12 @@ def cost_many(archs, trace, block_ops: int | None = None,
 
         def _fold_misses():
             nonlocal totals
-            for key, part in in_flight:
-                arr = np.asarray(part, np.int64)
-                cache.put(key, arr)
-                totals = totals + arr
-            in_flight.clear()
+            with telemetry.span("cost.fold"):
+                for key, part in in_flight:
+                    arr = np.asarray(part, np.int64)
+                    cache.put(key, arr)
+                    totals = totals + arr
+                in_flight.clear()
 
         for blk in blocks:
             compute_cycles += blk.compute_cycles
@@ -688,7 +744,8 @@ def cost_many(archs, trace, block_ops: int | None = None,
                 op_counts[k] = op_counts.get(k, 0) + v
             if not blk.n_ops:
                 continue
-            counter.add(blk)
+            with telemetry.span("cost.count"):
+                counter.add(blk)
             key = (table.digest,
                    cache.digest_of(blk.addrs, blk.mask, blk.kinds))
             hit = cache.get(key)
@@ -723,9 +780,10 @@ def cost_many(archs, trace, block_ops: int | None = None,
             if len(pending) == 1:
                 addrs, mask, kinds = pending[0]
             else:
-                addrs = np.concatenate([p[0] for p in pending])
-                mask = np.concatenate([p[1] for p in pending])
-                kinds = np.concatenate([p[2] for p in pending])
+                with telemetry.span("cost.coalesce"):
+                    addrs = np.concatenate([p[0] for p in pending])
+                    mask = np.concatenate([p[1] for p in pending])
+                    kinds = np.concatenate([p[2] for p in pending])
             pending.clear()
             pending_ops = 0
             partials.append(_dispatch(addrs, mask, kinds))
@@ -738,7 +796,8 @@ def cost_many(archs, trace, block_ops: int | None = None,
                 op_counts[k] = op_counts.get(k, 0) + v
             if not blk.n_ops:
                 continue
-            counter.add(blk)
+            with telemetry.span("cost.count"):
+                counter.add(blk)
             pending.append((blk.addrs,
                             np.ones_like(blk.addrs, bool) if blk.mask is None
                             else blk.mask,
@@ -749,26 +808,4 @@ def cost_many(archs, trace, block_ops: int | None = None,
         _flush()
         totals = _fold(totals, partials, n_archs)
 
-    n_instr, n_ops = counter.n_instr, counter.n_ops
-
-    costs = []
-    for i in range(n_archs):
-        r_ovh, w_ovh = (int(table.overheads[i, 0]),
-                        int(table.overheads[i, 1]))
-        kind_cycles = {
-            KIND_LOAD: int(totals[i, 0]) + int(n_instr[0]) * r_ovh,
-            KIND_STORE: int(totals[i, 1]) + int(n_instr[1]) * w_ovh,
-            KIND_TW: int(totals[i, 2]) + int(n_instr[2]) * r_ovh,
-        }
-        costs.append(TraceCost(
-            load_cycles=kind_cycles[KIND_LOAD] if n_ops[0] else 0,
-            store_cycles=kind_cycles[KIND_STORE] if n_ops[1] else 0,
-            tw_load_cycles=kind_cycles[KIND_TW] if n_ops[2] else 0,
-            compute_cycles=int(compute_cycles),
-            n_load_ops=int(n_ops[0]), n_store_ops=int(n_ops[1]),
-            n_tw_ops=int(n_ops[2]),
-            fp_ops=int(op_counts.get("fp", 0)),
-            int_ops=int(op_counts.get("int", 0)),
-            imm_ops=int(op_counts.get("imm", 0)),
-            other_ops=int(op_counts.get("other", 0))))
-    return costs
+    return totals, counter, compute_cycles, op_counts

@@ -95,6 +95,7 @@ def banked_gather_kernel(table_banked: jax.Array, idx: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, r, d), table_banked.dtype),
         interpret=registry.interpret_mode(),
+        name="banked_gather",
     )
     out = fn(idx.astype(jnp.int32), rows)
     return out.reshape((n,) + table_banked.shape[1:])

@@ -73,6 +73,7 @@ def banked_scatter_kernel(table_banked: jax.Array, idx: jax.Array,
         out_shape=jax.ShapeDtypeStruct((v, r, d), table_banked.dtype),
         input_output_aliases={2: 0},   # donate the table (arg 1 after idx)
         interpret=registry.interpret_mode(),
+        name="banked_scatter",
     )
     out = fn(idx.astype(jnp.int32), as_rows(updates), rows)
     return out.reshape(table_banked.shape)

@@ -419,6 +419,7 @@ def _decode_point(cfg, arch, batch: int, prompt_len: int, page_len: int):
     import jax.numpy as jnp
 
     from repro.core import arch as _arch
+    from repro.runtime import telemetry
     from repro.serving.kvcache import (PagedKVConfig, allocate_pages,
                                        init_pages, pool_pages)
     cfg = resolve_model_config(cfg)
@@ -432,17 +433,22 @@ def _decode_point(cfg, arch, batch: int, prompt_len: int, page_len: int):
         mapping=lay.mapping if lay is not None else "lsb",
         map_shift=lay.shift if lay is not None else 1,
         kv_heads=1, head_dim=1)
-    state = init_pages(kv_cfg, batch, max_seq)
-    ones = jnp.ones((batch,), bool)
-    for p in range(-(-prompt_len // page_len)):
+    n_prompt_pages = -(-prompt_len // page_len)
+    # the span ends at the page table's readback, which waits for the
+    # allocator's device work
+    with telemetry.span("trace.alloc"):
+        state = init_pages(kv_cfg, batch, max_seq)
+        ones = jnp.ones((batch,), bool)
+        for p in range(n_prompt_pages):
+            state = state._replace(
+                seq_lens=jnp.full((batch,), p * page_len, jnp.int32))
+            state, _ = allocate_pages(kv_cfg, state, ones)
         state = state._replace(
-            seq_lens=jnp.full((batch,), p * page_len, jnp.int32))
-        state, _ = allocate_pages(kv_cfg, state, ones)
-    state = state._replace(
-        seq_lens=jnp.full((batch,), prompt_len, jnp.int32))
-    need = (state.seq_lens % page_len) == 0
-    state, _ = allocate_pages(kv_cfg, state, need)
-    page_table = np.asarray(state.page_table)
+            seq_lens=jnp.full((batch,), prompt_len, jnp.int32))
+        need = (state.seq_lens % page_len) == 0
+        state, _ = allocate_pages(kv_cfg, state, need)
+        page_table = np.asarray(state.page_table)
+    telemetry.count("trace.alloc_calls", n_prompt_pages + 1)
     positions = np.full(batch, prompt_len, np.int64)
     return cfg, a, kv_cfg, page_table, positions
 

@@ -145,11 +145,12 @@ def apply_block_decode(cfg: ModelConfig, rc: RunConfig, p: dict, x: Array,
     if cfg.post_block_norms:
         h = L.rmsnorm(p["ln1_post"], h, cfg.norm_eps)
     x = x + h
-    h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    h = M.moe(cfg, rc, p["ffn"], h, ax)[0] if is_moe \
-        else L.mlp(cfg, p["ffn"], h, ax)
-    if cfg.post_block_norms:
-        h = L.rmsnorm(p["ln2_post"], h, cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
+        h = M.moe(cfg, rc, p["ffn"], h, ax)[0] if is_moe \
+            else L.mlp(cfg, p["ffn"], h, ax)
+        if cfg.post_block_norms:
+            h = L.rmsnorm(p["ln2_post"], h, cfg.norm_eps)
     return x + h, new_cache
 
 

@@ -36,6 +36,7 @@ from repro.core import arch as _arch
 from repro.launch.sharding import Axes
 from repro.models import layers as L
 from repro.models import transformer as T
+from repro.runtime import telemetry
 from repro.serving import kvcache as KV
 
 
@@ -265,41 +266,46 @@ class ServeEngine:
         n_pt = page_table.shape[1]
         s_all = n_pt * plen
         kvh, hd = cfg.n_kv_heads, cfg.hd
-        q, k_new, v_new = L._qkv(cfg, p, x, pos[:, None], ax)
-        ids = jnp.maximum(page_table, 0).reshape(-1)
-        ck = KV.gather_pages(arch, kv, cache["k"], ids)
-        cv = KV.gather_pages(arch, kv, cache["v"], ids)
-        ck = ck.reshape(b, s_all, kvh, hd)
-        cv = cv.reshape(b, s_all, kvh, hd)
-        idx = jnp.arange(s_all)
-        hot = ((idx[None, :] == pos[:, None])
-               & active[:, None])[:, :, None, None]
-        ck = jnp.where(hot, k_new.astype(ck.dtype), ck)
-        cv = jnp.where(hot, v_new.astype(cv.dtype), cv)
-        valid = ((idx[None, :] <= pos[:, None]) & active[:, None]
-                 & jnp.repeat(page_table >= 0, plen, axis=1))
-        if window:
-            valid &= (pos[:, None] - idx[None, :]) < window
-        s = jnp.einsum("bqkgh,btkh->bkgqt", q,
-                       ck.astype(q.dtype)) / math.sqrt(hd)
-        s = L.softcap(s, cfg.attn_softcap)
-        s = jnp.where(valid[:, None, None, None, :], s, L.NEG_INF)
-        pr = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-        o = jnp.einsum("bkgqt,btkh->bqkgh", pr, cv.astype(q.dtype))
-        o = o.reshape(b, 1, cfg.n_heads, hd)
-        out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
+        with jax.named_scope("qkv"):
+            q, k_new, v_new = L._qkv(cfg, p, x, pos[:, None], ax)
+        with jax.named_scope("page_gather"):
+            ids = jnp.maximum(page_table, 0).reshape(-1)
+            ck = KV.gather_pages(arch, kv, cache["k"], ids)
+            cv = KV.gather_pages(arch, kv, cache["v"], ids)
+            ck = ck.reshape(b, s_all, kvh, hd)
+            cv = cv.reshape(b, s_all, kvh, hd)
+        with jax.named_scope("attention"):
+            idx = jnp.arange(s_all)
+            hot = ((idx[None, :] == pos[:, None])
+                   & active[:, None])[:, :, None, None]
+            ck = jnp.where(hot, k_new.astype(ck.dtype), ck)
+            cv = jnp.where(hot, v_new.astype(cv.dtype), cv)
+            valid = ((idx[None, :] <= pos[:, None]) & active[:, None]
+                     & jnp.repeat(page_table >= 0, plen, axis=1))
+            if window:
+                valid &= (pos[:, None] - idx[None, :]) < window
+            s = jnp.einsum("bqkgh,btkh->bkgqt", q,
+                           ck.astype(q.dtype)) / math.sqrt(hd)
+            s = L.softcap(s, cfg.attn_softcap)
+            s = jnp.where(valid[:, None, None, None, :], s, L.NEG_INF)
+            pr = jax.nn.softmax(s.astype(jnp.float32),
+                                axis=-1).astype(q.dtype)
+            o = jnp.einsum("bkgqt,btkh->bqkgh", pr, cv.astype(q.dtype))
+            o = o.reshape(b, 1, cfg.n_heads, hd)
+            out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
         # per-lane read-modify-write append of each lane's current page
-        pg = jnp.minimum(pos // plen, n_pt - 1)
-        cur = jnp.where(active,
-                        jnp.maximum(page_table[jnp.arange(b), pg], 0),
-                        scratch)
-        line = (pg * plen)[:, None] + jnp.arange(plen)[None, :]
-        k_line = jnp.take_along_axis(ck, line[:, :, None, None], axis=1)
-        v_line = jnp.take_along_axis(cv, line[:, :, None, None], axis=1)
-        kp = KV.scatter_pages(arch, kv, cache["k"], cur,
-                              k_line.reshape((b,) + kv.page_shape))
-        vp = KV.scatter_pages(arch, kv, cache["v"], cur,
-                              v_line.reshape((b,) + kv.page_shape))
+        with jax.named_scope("page_scatter"):
+            pg = jnp.minimum(pos // plen, n_pt - 1)
+            cur = jnp.where(active,
+                            jnp.maximum(page_table[jnp.arange(b), pg], 0),
+                            scratch)
+            line = (pg * plen)[:, None] + jnp.arange(plen)[None, :]
+            k_line = jnp.take_along_axis(ck, line[:, :, None, None], axis=1)
+            v_line = jnp.take_along_axis(cv, line[:, :, None, None], axis=1)
+            kp = KV.scatter_pages(arch, kv, cache["k"], cur,
+                                  k_line.reshape((b,) + kv.page_shape))
+            vp = KV.scatter_pages(arch, kv, cache["v"], cur,
+                                  v_line.reshape((b,) + kv.page_shape))
         return out, {"k": kp, "v": vp}
 
     def _scheduler_step(self, params, tok, pools, page_table, pos, active,
@@ -311,7 +317,8 @@ class ServeEngine:
         ``_paged_step`` there is no in-graph ``allocate_pages``."""
         cfg, rc, ax = self.cfg, self.rc, self.ax
         dtype = jnp.dtype(rc.compute_dtype)
-        x = params["embed"].astype(dtype)[tok]
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(dtype)[tok]
         pattern = cfg.block_pattern()
         pools = dict(pools)
         attn_fn = functools.partial(
@@ -322,11 +329,13 @@ class ServeEngine:
                 p_sb = jax.tree.map(lambda a: a[sb],
                                     params["blocks"][f"b{j}"])
                 key = f"b{j}s{sb}"
-                x, pools[key] = T.apply_block_decode(
-                    cfg, rc, p_sb, x, pools[key], pos, ax, kind, is_moe,
-                    j, attn_fn=attn_fn)
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        logits = T._unembed(cfg, params, x)
+                with jax.named_scope(key):
+                    x, pools[key] = T.apply_block_decode(
+                        cfg, rc, p_sb, x, pools[key], pos, ax, kind, is_moe,
+                        j, attn_fn=attn_fn)
+        with jax.named_scope("unembed"):
+            x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            logits = T._unembed(cfg, params, x)
         return logits, pools
 
     def _prefill_rows(self, prompt: np.ndarray):
@@ -343,8 +352,6 @@ class ServeEngine:
         kv = self.kv_cfg
         plen = int(prompt.shape[0])
         n_pref = -(-plen // kv.page_len)
-        logits, cache = self._prefill(self.params, jnp.asarray(prompt)[None])
-        first = int(jnp.argmax(logits[0, -1, :self.cfg.vocab_size]))
 
         def rows_of(kc):
             # kc: (1, t, KV, HD) with t ≤ plen (SWA keeps only the window)
@@ -354,12 +361,17 @@ class ServeEngine:
             buf = buf.at[:, plen - t:plen].set(kc)
             return buf.reshape((n_pref,) + kv.page_shape)
 
-        rows = {}
-        for j, (kind, _) in enumerate(self.cfg.block_pattern()):
-            bc = cache["blocks"][f"b{j}"]
-            for sb in range(self.cfg.n_superblocks):
-                rows[f"b{j}s{sb}"] = {"k": rows_of(bc["k"][sb]),
-                                      "v": rows_of(bc["v"][sb])}
+        with telemetry.span("engine.prefill"):
+            logits, cache = self._prefill(self.params,
+                                          jnp.asarray(prompt)[None])
+            first = int(jnp.argmax(logits[0, -1, :self.cfg.vocab_size]))
+            with telemetry.span("engine.rows"):
+                rows = {}
+                for j, (kind, _) in enumerate(self.cfg.block_pattern()):
+                    bc = cache["blocks"][f"b{j}"]
+                    for sb in range(self.cfg.n_superblocks):
+                        rows[f"b{j}s{sb}"] = {"k": rows_of(bc["k"][sb]),
+                                              "v": rows_of(bc["v"][sb])}
         return first, rows
 
     def _scatter_rows(self, pools, rows, page_ids, page_start: int = 0):
@@ -367,22 +379,27 @@ class ServeEngine:
         pool at the scheduler-allocated ids — the live half of a prefill
         chunk (or, with ``page_start=0`` and all ids, of a whole-prompt
         admission)."""
-        ids = jnp.asarray(np.asarray(page_ids, np.int32))
-        n = int(ids.shape[0])
-        pools = dict(pools)
-        for key, pair in rows.items():
-            pools[key] = {
-                h: KV.scatter_pages(
-                    self.mem_arch, self.kv_cfg, pools[key][h], ids,
-                    pair[h][page_start:page_start + n])
-                for h in ("k", "v")}
+        telemetry.count("engine.scatter_calls", 2 * len(rows))
+        with telemetry.span("engine.scatter"):
+            ids = jnp.asarray(np.asarray(page_ids, np.int32))
+            n = int(ids.shape[0])
+            pools = dict(pools)
+            for key, pair in rows.items():
+                pools[key] = {
+                    h: KV.scatter_pages(
+                        self.mem_arch, self.kv_cfg, pools[key][h], ids,
+                        pair[h][page_start:page_start + n])
+                    for h in ("k", "v")}
         return pools
 
-    def _ingest_request(self, pools, prompt: np.ndarray, page_ids):
-        """Whole-prompt admission: prefill and scatter every prompt page
-        at once.  Returns the updated pools and the first token id."""
-        first, rows = self._prefill_rows(prompt)
-        return self._scatter_rows(pools, rows, page_ids), first
+    def _ingest_request(self, pools, prompt: np.ndarray, page_ids,
+                        rid: int | None = None):
+        """Whole-prompt admission of request ``rid``: prefill and scatter
+        every prompt page at once.  Returns the updated pools and the first
+        token id."""
+        with telemetry.span("engine.admit", rid=rid):
+            first, rows = self._prefill_rows(prompt)
+            return self._scatter_rows(pools, rows, page_ids), first
 
     def _migrate_pages(self, pools, old_ids, new_ids):
         """Evacuate a dying bank's live pages: gather each page's row from
@@ -416,7 +433,8 @@ class ServeEngine:
         pools = {key: {h: p.at[pid].set(0) for h, p in pair.items()}
                  for key, pair in pools.items()}
         pools, first = self._ingest_request(
-            pools, np.asarray(r.tokens, np.int32), rec["prompt_ids"])
+            pools, np.asarray(r.tokens, np.int32), rec["prompt_ids"],
+            rid=rid)
         seq = toks[rid]
         if seq and first != seq[0]:
             raise RuntimeError(
@@ -560,13 +578,15 @@ class ServeEngine:
                         f"with vocab_size= or attach tokens for live runs")
                 if sched.prefill_chunk_pages is None:
                     pools, first = self._ingest_request(
-                        pools, np.asarray(r.tokens, np.int32), adm.page_ids)
+                        pools, np.asarray(r.tokens, np.int32), adm.page_ids,
+                        rid=r.rid)
                 else:
                     # chunked admission: prefill now, HOLD the page rows;
                     # ev.prefill_chunks records (chunk 0 included) scatter
                     # them tick by tick as the scheduler lands the pages
-                    first, pending[adm.lane] = self._prefill_rows(
-                        np.asarray(r.tokens, np.int32))
+                    with telemetry.span("engine.admit", rid=r.rid):
+                        first, pending[adm.lane] = self._prefill_rows(
+                            np.asarray(r.tokens, np.int32))
                 lane_rid[adm.lane] = r.rid
                 toks[r.rid] = [first] if r.max_new_tokens >= 1 else []
                 lane_tok = lane_tok.at[adm.lane, 0].set(first)
@@ -580,29 +600,34 @@ class ServeEngine:
                 args = (self.params, lane_tok, pools,
                         jnp.asarray(ev.page_table), jnp.asarray(ev.pos),
                         jnp.asarray(ev.active), scratch)
-                if ev.transients:
-                    # injected transient faults: the step raises
-                    # ``failures`` times before succeeding, and the
-                    # production retry path absorbs every one of them
-                    budget = [ev.transients]
+                with telemetry.span("engine.decode"):
+                    if ev.transients:
+                        # injected transient faults: the step raises
+                        # ``failures`` times before succeeding, and the
+                        # production retry path absorbs every one of them
+                        budget = [ev.transients]
 
-                    def flaky():
-                        if budget[0] > 0:
-                            budget[0] -= 1
-                            raise TransientFault(
-                                f"injected decode fault at tick {ev.tick}")
-                        return self._decode_sched(*args)
+                        def flaky():
+                            if budget[0] > 0:
+                                budget[0] -= 1
+                                raise TransientFault(
+                                    f"injected decode fault at tick "
+                                    f"{ev.tick}")
+                            return self._decode_sched(*args)
 
-                    logits, pools = retry_step(
-                        flaky, retries=ev.transients, backoff=1e-6,
-                        retry_on=(TransientFault,), _sleep=lambda s: None)
-                else:
-                    logits, pools = self._decode_sched(*args)
+                        logits, pools = retry_step(
+                            flaky, retries=ev.transients, backoff=1e-6,
+                            retry_on=(TransientFault,),
+                            _sleep=lambda s: None)
+                    else:
+                        logits, pools = self._decode_sched(*args)
                 nxt = jnp.argmax(logits[:, -1, :self.cfg.vocab_size],
                                  axis=-1).astype(jnp.int32)[:, None]
                 lane_tok = jnp.where(jnp.asarray(ev.active)[:, None],
                                      nxt, lane_tok)
-                nxt_np = np.asarray(nxt[:, 0])
+                # the host waits here for the step's tokens
+                with telemetry.span("engine.readback"):
+                    nxt_np = np.asarray(nxt[:, 0])
                 for lane in np.flatnonzero(ev.active):
                     toks[int(lane_rid[lane])].append(int(nxt_np[lane]))
             self._sched_traces.extend(ev.traces)

@@ -48,6 +48,7 @@ from typing import Callable, Iterable, Iterator
 
 import numpy as np
 
+from repro.runtime import telemetry
 from repro.runtime.faults import FaultEvent, FaultPlan
 from repro.serving.kvcache import (PagedKVConfig, kv_read_stream, pool_pages,
                                    resolve_policy)
@@ -300,13 +301,14 @@ def admission_prefill_trace(cfg: PagedKVConfig, page_ids: np.ndarray,
     prompts at once)."""
     from repro.core.trace import AddressTrace
     from repro.kernels.banked_scatter.ops import banked_scatter_trace
-    ids = np.asarray(page_ids, np.int32).reshape(-1)
-    mask = np.ones(ids.shape[0], bool)
-    chunks = []
-    for _ in range(n_kv_layers):
-        chunks.append(banked_scatter_trace(None, None, ids, mask=mask))
-        chunks.append(banked_scatter_trace(None, None, ids, mask=mask))
-    t = AddressTrace.concat(*chunks)
+    with telemetry.span("sched.lower"):
+        ids = np.asarray(page_ids, np.int32).reshape(-1)
+        mask = np.ones(ids.shape[0], bool)
+        chunks = []
+        for _ in range(n_kv_layers):
+            chunks.append(banked_scatter_trace(None, None, ids, mask=mask))
+            chunks.append(banked_scatter_trace(None, None, ids, mask=mask))
+        t = AddressTrace.concat(*chunks)
     t.meta.update({"what": "sched_prefill", "rid": rid,
                    "n_pages": int(ids.shape[0]), "n_kv_layers": n_kv_layers})
     return t
@@ -356,27 +358,28 @@ def scheduler_step_trace(cfg: PagedKVConfig, page_table, pos, active,
     from repro.core.trace import AddressTrace
     from repro.kernels.banked_gather.ops import banked_gather_trace
     from repro.kernels.banked_scatter.ops import banked_scatter_trace
-    pt = np.asarray(page_table)
-    pos = np.asarray(pos)
-    active = np.asarray(active, bool)
-    b = pt.shape[0]
-    read_ids, read_mask = kv_read_stream(pt)
-    read_mask = read_mask & np.repeat(active, pt.shape[1])
-    cur = np.where(active, pt[np.arange(b),
-                              np.minimum(pos // cfg.page_len,
-                                         pt.shape[1] - 1)], -1)
-    cur_ids, cur_mask = np.maximum(cur, 0), cur >= 0
-    chunks = []
-    for _ in range(n_kv_layers):
-        chunks.append(banked_gather_trace(None, None, read_ids,
-                                          mask=read_mask))
-        chunks.append(banked_gather_trace(None, None, read_ids,
-                                          mask=read_mask))
-        chunks.append(banked_scatter_trace(None, None, cur_ids,
-                                           mask=cur_mask))
-        chunks.append(banked_scatter_trace(None, None, cur_ids,
-                                           mask=cur_mask))
-    t = AddressTrace.concat(*chunks)
+    with telemetry.span("sched.lower"):
+        pt = np.asarray(page_table)
+        pos = np.asarray(pos)
+        active = np.asarray(active, bool)
+        b = pt.shape[0]
+        read_ids, read_mask = kv_read_stream(pt)
+        read_mask = read_mask & np.repeat(active, pt.shape[1])
+        cur = np.where(active, pt[np.arange(b),
+                                  np.minimum(pos // cfg.page_len,
+                                             pt.shape[1] - 1)], -1)
+        cur_ids, cur_mask = np.maximum(cur, 0), cur >= 0
+        chunks = []
+        for _ in range(n_kv_layers):
+            chunks.append(banked_gather_trace(None, None, read_ids,
+                                              mask=read_mask))
+            chunks.append(banked_gather_trace(None, None, read_ids,
+                                              mask=read_mask))
+            chunks.append(banked_scatter_trace(None, None, cur_ids,
+                                               mask=cur_mask))
+            chunks.append(banked_scatter_trace(None, None, cur_ids,
+                                               mask=cur_mask))
+        t = AddressTrace.concat(*chunks)
     t.meta.update({"what": ("sched_decode_degraded" if degraded
                             else "sched_decode"), "tick": tick,
                    "active": int(active.sum()), "n_kv_layers": n_kv_layers})
@@ -528,9 +531,12 @@ class Scheduler:
         self._n_transients = 0
         self._n_preempts = 0
         #: optional straggler detection (``repro.runtime.StepWatchdog``):
-        #: tick() times each decode step with ``timer`` and feeds the
+        #: tick() times its decode phase with ``timer`` and feeds the
         #: watchdog; straggler ticks are recorded (chaining any caller
-        #: callback) and surfaced via ``stats()``.
+        #: callback) and surfaced via ``stats()``.  The decode phase is
+        #: the host's bookkeeping (page allocation, the step's trace):
+        #: in a live ``run_scheduler`` day the device step runs in the
+        #: engine after tick() returns, so ``timer`` does not see it.
         self._watchdog = watchdog
         self._timer = timer
         self._straggler_ticks: list[int] = []
@@ -732,8 +738,9 @@ class Scheduler:
             r = self.queue.pop(0)
             n_pref = -(-r.prompt_len // self.cfg.page_len)
             if self.prefill_chunk_pages is None:
-                ids = np.array([self.pool.alloc(k, r.rid)
-                                for k in range(n_pref)], np.int32)
+                with telemetry.span("sched.alloc"):
+                    ids = np.array([self.pool.alloc(k, r.rid)
+                                    for k in range(n_pref)], np.int32)
                 self.page_table[lane, :n_pref] = ids
                 self.lane_rid[lane] = r.rid
                 self.lane_pos[lane] = r.prompt_len
@@ -764,8 +771,9 @@ class Scheduler:
         n_pref = -(-r.prompt_len // self.cfg.page_len)
         start = self._prefill_next[lane]
         end = min(start + self.prefill_chunk_pages, n_pref)
-        ids = np.array([self.pool.alloc(k, r.rid)
-                        for k in range(start, end)], np.int32)
+        with telemetry.span("sched.alloc"):
+            ids = np.array([self.pool.alloc(k, r.rid)
+                            for k in range(start, end)], np.int32)
         self.page_table[lane, start:end] = ids
         done = end >= n_pref
         t = admission_prefill_trace(self.cfg, ids, self.n_kv_layers,
@@ -798,12 +806,13 @@ class Scheduler:
         active = (self.lane_rid >= 0) & (self.lane_steps_left > 0)
         if not active.any():
             return
-        for lane in np.flatnonzero(active):
-            pos = int(self.lane_pos[lane])
-            if pos % self.cfg.page_len == 0:
-                k = pos // self.cfg.page_len
-                self.page_table[lane, k] = self.pool.alloc(
-                    k, int(self.lane_rid[lane]))
+        with telemetry.span("sched.alloc"):
+            for lane in np.flatnonzero(active):
+                pos = int(self.lane_pos[lane])
+                if pos % self.cfg.page_len == 0:
+                    k = pos // self.cfg.page_len
+                    self.page_table[lane, k] = self.pool.alloc(
+                        k, int(self.lane_rid[lane]))
         ev.decoded = True
         ev.page_table = self.page_table.copy()
         ev.pos = self.lane_pos.copy()
@@ -819,23 +828,21 @@ class Scheduler:
         """Run one scheduler tick (see class docstring for the phases;
         fault events due at this tick fire FIRST, so migrations and
         recoveries see the lane state the fault struck)."""
-        ev = TickEvent(tick=self.now)
-        self._apply_faults(ev)
-        self._complete(ev)
-        self._prefill_continue(ev)
-        self._admit(ev)
-        t0 = self._timer()
-        self._decode(ev)
-        if ev.decoded and self._watchdog is not None:
-            self._watchdog.observe(self.now, self._timer() - t0)
-        self._busy_lane_ticks += int((self.lane_rid >= 0).sum())
-        if not ev.decoded and not self.queue and not self.done():
-            # only draining lanes remain: the next tick completes them
-            pass
-        self.now += 1
-        if (not ev.decoded and not ev.admitted and not ev.completed
-                and self.queue and (self.lane_rid < 0).all()):
-            self.now = max(self.now, self.queue[0].arrival)  # fast-forward
+        with telemetry.span("sched.tick"):
+            ev = TickEvent(tick=self.now)
+            self._apply_faults(ev)
+            self._complete(ev)
+            self._prefill_continue(ev)
+            self._admit(ev)
+            t0 = self._timer()
+            self._decode(ev)
+            if ev.decoded and self._watchdog is not None:
+                self._watchdog.observe(self.now, self._timer() - t0)
+            self._busy_lane_ticks += int((self.lane_rid >= 0).sum())
+            self.now += 1
+            if (not ev.decoded and not ev.admitted and not ev.completed
+                    and self.queue and (self.lane_rid < 0).all()):
+                self.now = max(self.now, self.queue[0].arrival)  # fast-forward
         return ev
 
     def run(self, requests: Iterable[Request] | None = None
