@@ -47,8 +47,10 @@ bigger than ``block_ops`` stream as ``instr_carry``-marked chunks, so a
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Iterator, Sequence
 
+import jax
 import numpy as np
 
 from repro.kernels.registry import Kernel, register
@@ -410,18 +412,25 @@ def _model_step_specs(cfg, kv_cfg, page_table, positions, batch: int,
             layer += 1
 
 
+@partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _decode_point_pages(kv_cfg, batch: int, max_seq: int, prompt_len: int):
+    """Every prompt page, then the decode step's page for the lanes at a
+    page boundary, allocated by the serving arbiter in one jitted call."""
+    from repro.serving.kvcache import allocate_pages, allocate_prompt_pages
+    state = allocate_prompt_pages(kv_cfg, batch, max_seq, prompt_len)
+    need = (state.seq_lens % kv_cfg.page_len) == 0
+    return allocate_pages(kv_cfg, state, need)[0]
+
+
 def _decode_point(cfg, arch, batch: int, prompt_len: int, page_len: int):
     """Shared lowering setup: resolve (config, arch), size the page pool
     from the arch's banked layout (multi-port memories price the canonical
     16-bank LSB pool, like ``simulate_serving_stream``), allocate every
     prompt page plus the decode-step page through the serving arbiter, and
     return (cfg, resolved arch, kv_cfg, page table, positions)."""
-    import jax.numpy as jnp
-
     from repro.core import arch as _arch
     from repro.runtime import telemetry
-    from repro.serving.kvcache import (PagedKVConfig, allocate_pages,
-                                       init_pages, pool_pages)
+    from repro.serving.kvcache import PagedKVConfig, pool_pages
     cfg = resolve_model_config(cfg)
     a = _arch.resolve(arch)
     max_seq = prompt_len + 1
@@ -433,22 +442,15 @@ def _decode_point(cfg, arch, batch: int, prompt_len: int, page_len: int):
         mapping=lay.mapping if lay is not None else "lsb",
         map_shift=lay.shift if lay is not None else 1,
         kv_heads=1, head_dim=1)
-    n_prompt_pages = -(-prompt_len // page_len)
     # the span ends at the page table's readback, which waits for the
     # allocator's device work
     with telemetry.span("trace.alloc"):
-        state = init_pages(kv_cfg, batch, max_seq)
-        ones = jnp.ones((batch,), bool)
-        for p in range(n_prompt_pages):
-            state = state._replace(
-                seq_lens=jnp.full((batch,), p * page_len, jnp.int32))
-            state, _ = allocate_pages(kv_cfg, state, ones)
-        state = state._replace(
-            seq_lens=jnp.full((batch,), prompt_len, jnp.int32))
-        need = (state.seq_lens % page_len) == 0
-        state, _ = allocate_pages(kv_cfg, state, need)
+        state = _decode_point_pages(kv_cfg, batch, max_seq, prompt_len)
         page_table = np.asarray(state.page_table)
-    telemetry.count("trace.alloc_calls", n_prompt_pages + 1)
+    # allocator rounds (prompt pages, then the decode step's), and the
+    # device dispatches that ran them
+    telemetry.count("trace.alloc_calls", -(-prompt_len // page_len) + 1)
+    telemetry.count("trace.alloc_dispatches", 1)
     positions = np.full(batch, prompt_len, np.int64)
     return cfg, a, kv_cfg, page_table, positions
 

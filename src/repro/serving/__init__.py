@@ -25,7 +25,8 @@ from repro.serving.engine import (GenerationResult, SchedulerRunResult,
                                   ServeEngine)
 from repro.serving.kvcache import (ALLOC_POLICIES, PagedKVConfig,
                                    PagedKVState, PageTableState,
-                                   allocate_pages, append_token,
+                                   allocate_pages, allocate_prompt_pages,
+                                   append_token,
                                    bank_load_stats, decode_step_trace,
                                    gather_kv, gather_pages, init_pages,
                                    init_state, pool_pages, prefill_trace,
@@ -41,7 +42,7 @@ __all__ = [
     "ServeEngine", "GenerationResult", "SchedulerRunResult",
     "PagedKVConfig", "PagedKVState", "PageTableState",
     "pool_pages", "init_pages", "init_state", "allocate_pages",
-    "append_token", "gather_kv", "bank_load_stats",
+    "allocate_prompt_pages", "append_token", "gather_kv", "bank_load_stats",
     "gather_pages", "scatter_pages",
     "decode_step_trace", "prefill_trace", "simulate_serving_trace",
     "simulate_serving_stream",

@@ -35,8 +35,10 @@ Three access paths share the layout:
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -48,7 +50,7 @@ Array = jnp.ndarray
 __all__ = [
     "PagedKVConfig", "PageTableState", "PagedKVState",
     "pool_pages", "init_pages", "init_state", "allocate_pages",
-    "append_token", "gather_kv", "bank_load_stats",
+    "allocate_prompt_pages", "append_token", "gather_kv", "bank_load_stats",
     "pool_rows", "gather_pages", "scatter_pages",
     "kv_read_stream", "decode_step_trace", "prefill_trace",
     "simulate_serving_trace", "simulate_serving_stream",
@@ -260,6 +262,29 @@ def allocate_pages(cfg: PagedKVConfig, state: PageTableState,
     return PageTableState(pt, state.seq_lens, new_used), page_id
 
 
+@partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def allocate_prompt_pages(cfg: PagedKVConfig, batch: int, max_seq: int,
+                          prompt_len: int) -> PageTableState:
+    """A fresh page table with every prompt page of ``batch`` sequences of
+    ``prompt_len`` tokens allocated, and ``seq_lens = prompt_len``.
+
+    Round ``p`` asks ``allocate_pages`` for every lane's page ``p`` (at
+    ``seq_lens = p · page_len``); the rounds run as a ``lax.fori_loop`` in
+    one jitted call: one device dispatch, one compile per (config, batch,
+    max_seq, prompt_len)."""
+    ones = jnp.ones((batch,), bool)
+
+    def round_(p, state):
+        state = state._replace(
+            seq_lens=jnp.full((batch,), p * cfg.page_len, jnp.int32))
+        return allocate_pages(cfg, state, ones)[0]
+
+    state = jax.lax.fori_loop(0, -(-prompt_len // cfg.page_len), round_,
+                              init_pages(cfg, batch, max_seq))
+    return state._replace(seq_lens=jnp.full((batch,), prompt_len,
+                                            jnp.int32))
+
+
 def _physical(cfg: PagedKVConfig, page_id: Array) -> Array:
     """Logical pool page id -> bank-major physical page (storage row)."""
     return cfg.layout.physical_row(page_id, cfg.n_pages)
@@ -467,14 +492,7 @@ def simulate_serving_stream(arch, batch: int, prompt_len: int,
             head_dim=1, map_shift=1)
 
     def blocks():
-        state = init_pages(cfg, batch, max_seq)
-        ones = jnp.ones((batch,), bool)
-        for p in range(-(-prompt_len // page_len)):     # prompt pages
-            state = state._replace(
-                seq_lens=jnp.full((batch,), p * page_len, jnp.int32))
-            state, _ = allocate_pages(cfg, state, ones)
-        state = state._replace(
-            seq_lens=jnp.full((batch,), prompt_len, jnp.int32))
+        state = allocate_prompt_pages(cfg, batch, max_seq, prompt_len)
         if include_prefill:
             yield prefill_trace(cfg, state.page_table, prompt_len,
                                 n_kv_layers)

@@ -184,6 +184,8 @@ def test_decode_point_counts_its_allocator_calls(tmp_path):
     snap = telemetry.snapshot()
     # two prompt pages, then the decode step's page (16 is on a boundary)
     assert snap["counters"]["trace.alloc_calls"] == 3
+    # all three rounds run in one jitted device call
+    assert snap["counters"]["trace.alloc_dispatches"] == 1
     assert ((page_table >= 0).sum(axis=1) == 3).all()
     assert snap["spans"]["trace.alloc"]["count"] == 1
 
