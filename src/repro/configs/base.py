@@ -31,6 +31,7 @@ class ModelConfig:
 
     # attention flavour
     rope_theta: float = 10000.0
+    attn_rope: bool = True                 # False: jamba (no positional enc.)
     qkv_bias: bool = False                 # qwen1.5
     sliding_window: int = 0                # mixtral SWA (0 = full)
     local_global: bool = False             # gemma2 alternating local/global
@@ -53,6 +54,7 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_conv: int = 4
     ssm_dt_rank: int = 0                   # 0 -> ceil(d_model / 16)
+    ssm_dt_bc_norms: bool = False          # jamba: RMSNorm on dt, B, C
     attn_period: int = 0                   # hybrid: attention every k-th layer
     attn_offset: int = 0                   # ... at (i % period) == offset
 
@@ -137,6 +139,8 @@ class ModelConfig:
                        + dtr * di + di       # dt_proj
                        + di * st + di        # A_log, D
                        + di * d)             # out_proj
+                if self.ssm_dt_bc_norms:
+                    mix += dtr + 2 * st      # dt, B, C norm weights
             if self.is_moe_layer(i):
                 ff_tot = self.n_experts * 3 * d * self.d_ff + d * self.n_experts
                 ff_act = self.experts_per_token * 3 * d * self.d_ff

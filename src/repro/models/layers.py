@@ -92,8 +92,9 @@ def _qkv(cfg: ModelConfig, p: dict, x: Array, positions: Array, ax: Axes):
         q = q + p["bq"].astype(dt)
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.attn_rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     q = ax.heads_act(q)
     k = ax.heads_act(k)
     v = ax.heads_act(v)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.launch.sharding import Axes
+from repro.models.layers import rmsnorm
 from repro.models.params import Leaf, fan_in_scale
 
 Array = jnp.ndarray
@@ -26,6 +27,11 @@ Array = jnp.ndarray
 def ssm_specs(cfg: ModelConfig) -> dict:
     d, di, n, r, k = (cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.dt_rank,
                       cfg.ssm_conv)
+    norms = {
+        "dt_norm": Leaf((r,), ("dt_rank",), init="ones"),
+        "b_norm": Leaf((n,), ("state",), init="ones"),
+        "c_norm": Leaf((n,), ("state",), init="ones"),
+    } if cfg.ssm_dt_bc_norms else {}
     return {
         "in_proj": Leaf((d, 2 * di), ("embed", "dinner"), scale=fan_in_scale(d)),
         "conv_w": Leaf((k, di), ("conv", "dinner"), scale=fan_in_scale(k)),
@@ -37,6 +43,7 @@ def ssm_specs(cfg: ModelConfig) -> dict:
         "A_log": Leaf((di, n), ("dinner", "state"), init="ones"),
         "D_skip": Leaf((di,), ("dinner",), init="ones"),
         "out_proj": Leaf((di, d), ("dinner", "embed"), scale=fan_in_scale(di)),
+        **norms,
     }
 
 
@@ -57,6 +64,10 @@ def _ssm_inputs(cfg: ModelConfig, p: dict, u: Array):
     dt = u.dtype
     proj = jnp.einsum("...sd,dk->...sk", u, p["x_proj"].astype(dt))
     dt_raw, bmat, cmat = jnp.split(proj, [r, r + n], axis=-1)
+    if cfg.ssm_dt_bc_norms:                  # jamba's mixer
+        dt_raw = rmsnorm(p["dt_norm"], dt_raw, cfg.norm_eps)
+        bmat = rmsnorm(p["b_norm"], bmat, cfg.norm_eps)
+        cmat = rmsnorm(p["c_norm"], cmat, cfg.norm_eps)
     delta = jax.nn.softplus(
         jnp.einsum("...sr,rd->...sd", dt_raw, p["dt_proj"].astype(dt))
         + p["dt_bias"].astype(dt))                              # (...,S,Di)
@@ -80,7 +91,12 @@ def _scan_chunk(carry_h: Array, abar: Array, bbar: Array) -> tuple:
 
 def mamba_prefill(cfg: ModelConfig, p: dict, x: Array, ax: Axes,
                   chunk: int = 256):
-    """x: (B, S, D) -> (y (B, S, D), decode-ready state cache)."""
+    """x: (B, S, D) -> (y (B, S, D), decode-ready state cache).
+
+    Any S: a last chunk that S does not fill is padded with identity steps
+    (``delta`` = 0, so ``abar`` = 1 and ``bbar`` = 0), which leave the
+    carried state as the last token left it and whose outputs are dropped.
+    """
     b, s, d = x.shape
     di, n = cfg.d_inner, cfg.ssm_state
     dt = x.dtype
@@ -92,8 +108,8 @@ def mamba_prefill(cfg: ModelConfig, p: dict, x: Array, ax: Axes,
     delta, bmat, cmat, a = _ssm_inputs(cfg, p, u)
 
     chunk = min(chunk, s)
-    assert s % chunk == 0
-    nchunks = s // chunk
+    nchunks = -(-s // chunk)
+    pad = nchunks * chunk - s
 
     def body(h, args):
         u_c, delta_c, b_c, c_c = args
@@ -105,18 +121,26 @@ def mamba_prefill(cfg: ModelConfig, p: dict, x: Array, ax: Axes,
         return h_last, y.astype(dt)
 
     def split_chunks(t):
+        if pad:
+            t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
         return t.reshape(b, nchunks, chunk, *t.shape[2:]).swapaxes(0, 1)
 
     h0 = jnp.zeros((b, di, n), jnp.float32)
     h_final, ys = jax.lax.scan(
         body, h0, (split_chunks(u), split_chunks(delta),
                    split_chunks(bmat), split_chunks(cmat)))
-    y = ys.swapaxes(0, 1).reshape(b, s, di)
+    y = ys.swapaxes(0, 1).reshape(b, nchunks * chunk, di)
+    if pad:
+        y = y[:, :s]
     y = y + u * p["D_skip"].astype(dt)
     y = y * jax.nn.silu(z)
     out = jnp.einsum("bsi,id->bsd", y, p["out_proj"].astype(dt))
+    k = cfg.ssm_conv
+    tail = u_pre[:, -(k - 1):]
+    if s < k - 1:                # a shorter prompt's window is zero-led
+        tail = jnp.pad(tail, ((0, 0), (k - 1 - s, 0), (0, 0)))
     cache = {"h": h_final,                                   # (B, Di, N)
-             "conv": u_pre[:, -(cfg.ssm_conv - 1):]}         # (B, K-1, Di)
+             "conv": tail}                                   # (B, K-1, Di)
     return out, cache
 
 

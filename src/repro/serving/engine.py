@@ -92,6 +92,15 @@ class ServeEngine:
                                                      pos, ax))
         self._decode_paged = jax.jit(self._paged_step)
         self._decode_sched = jax.jit(self._scheduler_step)
+        #: pool keys of the Mamba layers: in ``run_scheduler`` each holds
+        #: one lane-indexed SSM state slot per lane (slot = lane) instead
+        #: of a K/V page pool; empty for an attention-only model
+        self._ssm_keys = tuple(
+            f"b{j}s{sb}" for j, (kind, _) in enumerate(cfg.block_pattern())
+            if kind != "attn" for sb in range(cfg.n_superblocks))
+        # the old slots are dead once written: donated, so a write costs
+        # one lane's rows and not a copy of every slot
+        self._slot_write = jax.jit(self._write_slot_rows, donate_argnums=0)
         self._step_traces: list = []
         self._prefill_trace = None
         self._sched_traces: list = []
@@ -125,6 +134,11 @@ class ServeEngine:
         """Attention layers with a KV pool (pattern attn blocks × scan)."""
         return self.cfg.n_superblocks * sum(
             1 for kind, _ in self.cfg.block_pattern() if kind == "attn")
+
+    @property
+    def n_ssm_layers(self) -> int:
+        """Mamba layers, each with a per-lane SSM state slot."""
+        return len(self._ssm_keys)
 
     # -- paged decode path -------------------------------------------------
 
@@ -314,7 +328,10 @@ class ServeEngine:
         table, per-lane positions and active mask are traced values with
         static shapes, so admissions/completions never recompile).  The
         host-side ``scheduler.Scheduler`` owns allocation — unlike
-        ``_paged_step`` there is no in-graph ``allocate_pages``."""
+        ``_paged_step`` there is no in-graph ``allocate_pages``.  A Mamba
+        layer's ``pools`` entry is its lane-indexed SSM state
+        (``{"h": (B, Di, N) f32, "conv": (B, K-1, Di)}``), advanced for
+        the ``active`` lanes only."""
         cfg, rc, ax = self.cfg, self.rc, self.ax
         dtype = jnp.dtype(rc.compute_dtype)
         with jax.named_scope("embed"):
@@ -330,9 +347,17 @@ class ServeEngine:
                                     params["blocks"][f"b{j}"])
                 key = f"b{j}s{sb}"
                 with jax.named_scope(key):
-                    x, pools[key] = T.apply_block_decode(
+                    x, new = T.apply_block_decode(
                         cfg, rc, p_sb, x, pools[key], pos, ax, kind, is_moe,
                         j, attn_fn=attn_fn)
+                    if kind != "attn":
+                        # an idle or still-prefilling lane keeps its SSM
+                        # slot bit for bit
+                        new = jax.tree.map(
+                            lambda n, o: jnp.where(
+                                active.reshape((-1,) + (1,) * (o.ndim - 1)),
+                                n.astype(o.dtype), o), new, pools[key])
+                    pools[key] = new
         with jax.named_scope("unembed"):
             x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
             logits = T._unembed(cfg, params, x)
@@ -340,11 +365,13 @@ class ServeEngine:
 
     def _prefill_rows(self, prompt: np.ndarray):
         """Prefill ONE request and lower its K/V to page rows: returns the
-        request's first generated token id and a per-pool dict of
+        request's first generated token id, a per-KV-pool dict of
         ``(n_pref,) + page_shape`` page arrays — page ``k`` at index
         ``k``, ready to scatter at whatever tick the scheduler lands that
         page (whole-prompt admission scatters all rows at once; chunked
-        prefill scatters slices as ``ev.prefill_chunks`` records arrive).
+        prefill scatters slices as ``ev.prefill_chunks`` records arrive) —
+        and the prompt's final SSM state of every Mamba block (``{}`` for
+        an attention-only model; ``_write_slots`` lands it).
         One jit compile per distinct prompt length.  K/V slots past the
         prompt in its last page stay zero; every decode mask is
         ``idx <= pos``, so a stale slot is never read before the decode
@@ -366,13 +393,39 @@ class ServeEngine:
                                           jnp.asarray(prompt)[None])
             first = int(jnp.argmax(logits[0, -1, :self.cfg.vocab_size]))
             with telemetry.span("engine.rows"):
-                rows = {}
+                rows, state = {}, {}
                 for j, (kind, _) in enumerate(self.cfg.block_pattern()):
                     bc = cache["blocks"][f"b{j}"]
+                    if kind != "attn":
+                        state[f"b{j}"] = bc      # (n_superblocks, 1, ...)
+                        continue
                     for sb in range(self.cfg.n_superblocks):
                         rows[f"b{j}s{sb}"] = {"k": rows_of(bc["k"][sb]),
                                               "v": rows_of(bc["v"][sb])}
-        return first, rows
+        return first, rows, state
+
+    def _write_slot_rows(self, slots, state, lane):
+        """Every Mamba layer's slot with lane ``lane``'s row replaced by
+        one prefill's final state (jit'd once: ``lane`` is traced)."""
+        out = {}
+        for j, (kind, _) in enumerate(self.cfg.block_pattern()):
+            if kind == "attn":
+                continue
+            for sb in range(self.cfg.n_superblocks):
+                key = f"b{j}s{sb}"
+                out[key] = {
+                    name: slot.at[lane].set(
+                        state[f"b{j}"][name][sb, 0].astype(slot.dtype))
+                    for name, slot in slots[key].items()}
+        return out
+
+    def _write_slots(self, pools, state, lane: int):
+        """Land an admitted request's prefill state in its lane's SSM slot
+        of every Mamba layer: one jitted call, its old slots donated."""
+        telemetry.count("engine.ssm_slot_writes", 2 * len(self._ssm_keys))
+        with telemetry.span("engine.ssm_slot"):
+            slots = {key: pools[key] for key in self._ssm_keys}
+            return {**pools, **self._slot_write(slots, state, lane)}
 
     def _scatter_rows(self, pools, rows, page_ids, page_start: int = 0):
         """Scatter one contiguous slice of held prefill rows into every
@@ -393,13 +446,17 @@ class ServeEngine:
         return pools
 
     def _ingest_request(self, pools, prompt: np.ndarray, page_ids,
-                        rid: int | None = None):
-        """Whole-prompt admission of request ``rid``: prefill and scatter
-        every prompt page at once.  Returns the updated pools and the first
+                        rid: int | None = None, lane: int | None = None):
+        """Whole-prompt admission of request ``rid`` into ``lane``: prefill
+        and scatter every prompt page at once (and, for a hybrid, write
+        the lane's SSM slots).  Returns the updated pools and the first
         token id."""
         with telemetry.span("engine.admit", rid=rid):
-            first, rows = self._prefill_rows(prompt)
-            return self._scatter_rows(pools, rows, page_ids), first
+            first, rows, state = self._prefill_rows(prompt)
+            pools = self._scatter_rows(pools, rows, page_ids)
+            if state:
+                pools = self._write_slots(pools, state, lane)
+            return pools, first
 
     def _migrate_pages(self, pools, old_ids, new_ids):
         """Evacuate a dying bank's live pages: gather each page's row from
@@ -412,6 +469,8 @@ class ServeEngine:
         new = jnp.asarray(np.asarray(new_ids, np.int32))
         pools = dict(pools)
         for key, pair in pools.items():
+            if key in self._ssm_keys:        # SSM slots hold no pages
+                continue
             out = {}
             for half in ("k", "v"):
                 rows = KV.gather_pages(self.mem_arch, kv, pair[half], old)
@@ -426,15 +485,19 @@ class ServeEngine:
         re-prefill the victim request's prompt pages, then replay its
         completed decode steps feeding the recorded tokens.  Every replayed
         token is pinned against the original — recovery that silently
-        diverges is an error, not a degraded answer."""
+        diverges is an error, not a degraded answer.  A hybrid lane's SSM
+        slots are rebuilt on the way: the re-prefill rewrites them with the
+        prompt's state and each replayed step (only the victim lane active)
+        advances them by one served token."""
         r = rec["request"]
         rid, lane = rec["rid"], rec["lane"]
         pid = int(rec["pid"])
-        pools = {key: {h: p.at[pid].set(0) for h, p in pair.items()}
+        pools = {key: (pair if key in self._ssm_keys else
+                       {h: p.at[pid].set(0) for h, p in pair.items()})
                  for key, pair in pools.items()}
         pools, first = self._ingest_request(
             pools, np.asarray(r.tokens, np.int32), rec["prompt_ids"],
-            rid=rid)
+            rid=rid, lane=lane)
         seq = toks[rid]
         if seq and first != seq[0]:
             raise RuntimeError(
@@ -478,9 +541,16 @@ class ServeEngine:
         stays bit-equal across every chunk boundary.
 
         Requests need prompt ``tokens``; admission order, page placement
-        and completion order are exactly the simulation's.  The live path
-        requires an attention-only model (SSM/hybrid lane state is not
-        re-admittable yet — simulation and costing work for any traffic).
+        and completion order are exactly the simulation's.
+
+        Hybrids (Mamba + attention) are served too: each Mamba layer keeps
+        a lane-indexed SSM state slot in ``pools`` beside the attention
+        layers' page pools (slot = lane).  Admission writes the prompt's
+        final state into the lane's slot (a chunked admission when its last
+        chunk lands), the decode step advances active lanes only, and a
+        checkpoint carries the slots with the pools.  The recorded trace
+        lowers the attention layers' KV traffic only (``n_kv_layers``).
+        An attention-only model allocates no slot.
 
         Fault tolerance (docs/ROBUSTNESS.md): ``fault_plan`` injects a
         seeded ``repro.runtime.FaultPlan`` timeline — bank losses migrate
@@ -498,23 +568,30 @@ class ServeEngine:
         from repro.serving.scheduler import Scheduler
         if self.kv_mode != "paged":
             raise ValueError("run_scheduler requires kv_mode='paged'")
-        if any(kind != "attn" for kind, _ in self.cfg.block_pattern()):
-            raise NotImplementedError(
-                "run_scheduler supports attention-only models (per-lane "
-                "SSM state eviction/re-admission is not implemented); "
-                "hybrid traffic can still be simulated and costed via "
-                "scheduler.simulate_scheduler_stream")
         if resume_from is not None and requests is not None:
             raise ValueError("pass requests=None when resuming: the "
                              "checkpointed scheduler still holds them")
         sched = scheduler or Scheduler(
             self.kv_cfg, n_lanes=self.max_batch, max_seq=self.max_seq,
             policy=policy, n_kv_layers=self.n_kv_layers,
-            fault_plan=fault_plan, prefill_chunk_pages=prefill_chunk_pages)
+            fault_plan=fault_plan, prefill_chunk_pages=prefill_chunk_pages,
+            n_ssm_layers=self.n_ssm_layers)
+        if sched.n_ssm_layers != self.n_ssm_layers:
+            raise ValueError(
+                f"the scheduler carries {sched.n_ssm_layers} SSM layers, "
+                f"the model {self.n_ssm_layers}")
         dtype = jnp.dtype(self.rc.compute_dtype)
+        cfg = self.cfg
         pools = {}
-        for j, (kind, _) in enumerate(self.cfg.block_pattern()):
-            for sb in range(self.cfg.n_superblocks):
+        for j, (kind, _) in enumerate(cfg.block_pattern()):
+            for sb in range(cfg.n_superblocks):
+                if kind != "attn":
+                    pools[f"b{j}s{sb}"] = {
+                        "h": jnp.zeros((self.max_batch, cfg.d_inner,
+                                        cfg.ssm_state), jnp.float32),
+                        "conv": jnp.zeros((self.max_batch, cfg.ssm_conv - 1,
+                                           cfg.d_inner), dtype)}
+                    continue
                 zero = jnp.zeros((self.kv_cfg.n_pages,)
                                  + self.kv_cfg.page_shape, dtype)
                 pools[f"b{j}s{sb}"] = {"k": zero, "v": zero}
@@ -526,6 +603,9 @@ class ServeEngine:
         #: held prefill rows of lanes mid-chunked-prefill
         #: (lane -> per-pool row arrays; see ``_prefill_rows``)
         pending: dict[int, dict] = {}
+        #: a hybrid's held prefill SSM state of those lanes, written into
+        #: the lane's slots when the last chunk lands
+        held_state: dict[int, dict] = {}
         if resume_from is not None:
             step = latest_step(resume_from)
             if step is None:
@@ -552,8 +632,10 @@ class ServeEngine:
             # run's)
             for lane in sched._prefill_next:
                 r = sched._by_rid[int(sched.lane_rid[lane])]
-                _, pending[lane] = self._prefill_rows(
+                _, pending[lane], state = self._prefill_rows(
                     np.asarray(r.tokens, np.int32))
+                if state:
+                    held_state[lane] = state
         self._sched_traces = []
         preempted, ckpt_path = False, None
         for ev in sched.run(requests):
@@ -570,6 +652,7 @@ class ServeEngine:
                     toks.pop(c.request.rid, []), np.int32)
                 lane_rid[c.lane] = -1
                 pending.pop(c.lane, None)    # cancelled mid-prefill
+                held_state.pop(c.lane, None)
             for adm in ev.admitted:
                 r = adm.request
                 if r.tokens is None:
@@ -579,14 +662,17 @@ class ServeEngine:
                 if sched.prefill_chunk_pages is None:
                     pools, first = self._ingest_request(
                         pools, np.asarray(r.tokens, np.int32), adm.page_ids,
-                        rid=r.rid)
+                        rid=r.rid, lane=adm.lane)
                 else:
-                    # chunked admission: prefill now, HOLD the page rows;
-                    # ev.prefill_chunks records (chunk 0 included) scatter
-                    # them tick by tick as the scheduler lands the pages
+                    # chunked admission: prefill now, HOLD the page rows
+                    # (and SSM state); ev.prefill_chunks records (chunk 0
+                    # included) scatter them tick by tick as the scheduler
+                    # lands the pages
                     with telemetry.span("engine.admit", rid=r.rid):
-                        first, pending[adm.lane] = self._prefill_rows(
+                        first, pending[adm.lane], state = self._prefill_rows(
                             np.asarray(r.tokens, np.int32))
+                    if state:
+                        held_state[adm.lane] = state
                 lane_rid[adm.lane] = r.rid
                 toks[r.rid] = [first] if r.max_new_tokens >= 1 else []
                 lane_tok = lane_tok.at[adm.lane, 0].set(first)
@@ -596,6 +682,10 @@ class ServeEngine:
                                            chunk["page_start"])
                 if chunk["done"]:
                     del pending[chunk["lane"]]
+                    if chunk["lane"] in held_state:
+                        pools = self._write_slots(
+                            pools, held_state.pop(chunk["lane"]),
+                            chunk["lane"])
             if ev.decoded:
                 args = (self.params, lane_tok, pools,
                         jnp.asarray(ev.page_table), jnp.asarray(ev.pos),

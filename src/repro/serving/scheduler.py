@@ -470,6 +470,10 @@ class Scheduler:
     scatter the same chunks from held prefill rows, so live == sim stays
     bit-equal across every chunk boundary (pinned in
     tests/test_scheduler.py).
+
+    A hybrid model's lane also owns one SSM state slot per Mamba layer
+    (``n_ssm_layers``; slot = lane): see ``ssm_slot_live`` for its
+    lifecycle.  The trace blocks lower KV traffic only.
     """
 
     def __init__(self, cfg: PagedKVConfig, n_lanes: int = 16,
@@ -477,12 +481,16 @@ class Scheduler:
                  n_kv_layers: int = 1, reserve_scratch: bool = True,
                  fault_plan: FaultPlan | None = None,
                  prefill_chunk_pages: int | None = None,
-                 watchdog=None, timer: Callable[[], float] = time.perf_counter):
+                 watchdog=None, timer: Callable[[], float] = time.perf_counter,
+                 n_ssm_layers: int = 0):
         self.cfg = cfg
         self.n_lanes = n_lanes
         self.max_seq = max_seq
         self.max_pages = -(-max_seq // cfg.page_len)
         self.n_kv_layers = n_kv_layers
+        #: Mamba layers whose per-lane state slot each lane carries beside
+        #: its pages (0: an attention-only model); construction config
+        self.n_ssm_layers = n_ssm_layers
         #: chunked prefill (None = classic whole-prompt admission): a long
         #: prompt's ingest is split into chunks of at most this many pages,
         #: one chunk per tick, INTERLEAVED with other lanes' decode steps —
@@ -708,6 +716,20 @@ class Scheduler:
     def done(self) -> bool:
         return not self.queue and bool((self.lane_rid < 0).all())
 
+    @property
+    def ssm_slot_live(self) -> np.ndarray:
+        """(n_lanes,) bool: lanes whose SSM slots hold a resident
+        request's state.  Slot = lane, so the slot's lifecycle is the
+        lane's: taken when the prompt has landed (at admission, or with
+        the last chunk of a chunked one), freed on completion and on
+        ``cancel``, carried by ``state_dict`` / ``load_state`` with the
+        lane state it is derived from.  A bank loss moves pages only; a
+        page-corrupt recovery's re-prefill and replay rebuild the slots in
+        the live engine.  All False for an attention-only model."""
+        live = (self.lane_rid >= 0) & (self.n_ssm_layers > 0)
+        live[list(self._prefill_next)] = False
+        return live
+
     def _complete(self, ev: TickEvent) -> None:
         for lane in range(self.n_lanes):
             rid = int(self.lane_rid[lane])
@@ -839,6 +861,10 @@ class Scheduler:
             if ev.decoded and self._watchdog is not None:
                 self._watchdog.observe(self.now, self._timer() - t0)
             self._busy_lane_ticks += int((self.lane_rid >= 0).sum())
+            if self.n_ssm_layers:
+                telemetry.count("sched.ssm_slots_live",
+                                int(self.ssm_slot_live.sum()))
+                telemetry.count("sched.ssm_slot_ticks", self.n_lanes)
             self.now += 1
             if (not ev.decoded and not ev.admitted and not ev.completed
                     and self.queue and (self.lane_rid < 0).all()):

@@ -271,3 +271,39 @@ def test_run_scheduler_serves_identical_tokens_traced_and_counts_admissions(
         assert scatters == len(TRAFFIC)
     else:
         assert scatters == sum(-(-p // 8) for _, p, _ in TRAFFIC)
+
+
+@pytest.mark.parametrize("chunk", [None, 1])
+def test_a_hybrid_day_counts_its_ssm_slot_writes_and_occupancy(tmp_path,
+                                                                chunk):
+    from repro.configs.base import ModelConfig
+    cfg = ModelConfig(name="tiny-jamba", family="hybrid", n_layers=4,
+                      d_model=32, n_heads=4, n_kv_heads=1, d_ff=64,
+                      vocab_size=LM.vocab_size, head_dim=8, ssm_state=4,
+                      ssm_dt_rank=4, attn_period=2, attn_offset=1,
+                      attn_rope=False, ssm_dt_bc_norms=True)
+    params = init_tree(model_specs(cfg), jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, RunConfig(remat="none", attn_impl="dense"),
+                      params, NO_AXES, max_batch=4, max_seq=32,
+                      kv_mode="paged", page_len=8)
+    off = eng.run_scheduler(_requests(), prefill_chunk_pages=chunk)
+    with jax.profiler.trace(str(tmp_path)):
+        on = eng.run_scheduler(_requests(), prefill_chunk_pages=chunk)
+    for rid in off.outputs:
+        np.testing.assert_array_equal(on.outputs[rid], off.outputs[rid])
+    snap = telemetry.snapshot()
+    spans, counters = snap["spans"], snap["counters"]
+    # one slot write per admission, two arrays (h, conv) per Mamba layer
+    assert eng.n_ssm_layers == 2
+    assert spans["engine.ssm_slot"]["count"] == len(TRAFFIC)
+    assert counters["engine.ssm_slot_writes"] == 2 * 2 * len(TRAFFIC)
+    recs = telemetry.records()
+    parents = {recs[r.parent].name if r.parent is not None else None
+               for r in recs if r.name == "engine.ssm_slot"}
+    assert parents == ({"engine.admit"} if chunk is None else {None})
+    # lanes x ticks, and of it the lane-ticks whose slot held a request
+    assert counters["sched.ssm_slot_ticks"] == 4 * on.ticks
+    live = counters["sched.ssm_slots_live"]
+    busy = round(on.stats["lane_occupancy"] * 4 * on.ticks)
+    # a chunked admission's slot is live from its last chunk on
+    assert live == busy if chunk is None else 0 < live < busy
