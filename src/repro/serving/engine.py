@@ -87,6 +87,14 @@ class ServeEngine:
                        if kv_mode == "paged" else None)
         self._prefill = jax.jit(
             lambda p, t: T.prefill(cfg, rc, p, t, ax))
+        # an admission's three device calls: prefill with its first token,
+        # the lowering of every attention layer's K/V to page rows (one
+        # compile per prompt length), and the scatter of those rows into
+        # the KV pools, which are donated: an undonated call over every
+        # pool would hold a second copy of each
+        self._prefill_first = jax.jit(self._prefill_first_token)
+        self._lower_rows = jax.jit(self._cache_rows, static_argnums=1)
+        self._land_rows = jax.jit(self._scatter_pool_rows, donate_argnums=0)
         self._decode = jax.jit(
             lambda p, tok, cache, pos: T.decode_step(cfg, rc, p, tok, cache,
                                                      pos, ax))
@@ -363,45 +371,55 @@ class ServeEngine:
             logits = T._unembed(cfg, params, x)
         return logits, pools
 
-    def _prefill_rows(self, prompt: np.ndarray):
-        """Prefill ONE request and lower its K/V to page rows: returns the
-        request's first generated token id, a per-KV-pool dict of
-        ``(n_pref,) + page_shape`` page arrays — page ``k`` at index
-        ``k``, ready to scatter at whatever tick the scheduler lands that
-        page (whole-prompt admission scatters all rows at once; chunked
-        prefill scatters slices as ``ev.prefill_chunks`` records arrive) —
-        and the prompt's final SSM state of every Mamba block (``{}`` for
-        an attention-only model; ``_write_slots`` lands it).
-        One jit compile per distinct prompt length.  K/V slots past the
-        prompt in its last page stay zero; every decode mask is
-        ``idx <= pos``, so a stale slot is never read before the decode
-        step that writes it."""
+    def _prefill_first_token(self, params, tokens):
+        """``T.prefill`` of one request and its greedy first token."""
+        logits, cache = T.prefill(self.cfg, self.rc, params, tokens, self.ax)
+        return jnp.argmax(logits[0, -1, :self.cfg.vocab_size]), cache
+
+    def _cache_rows(self, cache, plen: int):
+        """Every attention block's stacked K/V prefill cache
+        ``(n_superblocks, 1, t, KV, HD)`` lowered to page rows
+        ``(n_superblocks, n_pref) + page_shape``, keyed ``b{j}`` (jit'd
+        once per prompt length ``plen``).  ``t`` ≤ ``plen``: SWA keeps only
+        the window, so the rows before it and past the prompt stay zero.
+        One superblock at a time (``lax.map``): lowering the whole stack
+        at once makes the TPU compiler stage a relayout copy of it."""
         kv = self.kv_cfg
-        plen = int(prompt.shape[0])
         n_pref = -(-plen // kv.page_len)
 
-        def rows_of(kc):
-            # kc: (1, t, KV, HD) with t ≤ plen (SWA keeps only the window)
-            t = kc.shape[1]
-            buf = jnp.zeros((1, n_pref * kv.page_len) + kc.shape[2:],
-                            kc.dtype)
-            buf = buf.at[:, plen - t:plen].set(kc)
-            return buf.reshape((n_pref,) + kv.page_shape)
+        def lower(c):                   # one superblock: (1, t, KV, HD)
+            pad = [(0, 0)] * c.ndim
+            pad[1] = (plen - c.shape[1], n_pref * kv.page_len - plen)
+            return jnp.pad(c, pad).reshape((n_pref,) + kv.page_shape)
 
+        return {f"b{j}": {h: jax.lax.map(lower, cache["blocks"][f"b{j}"][h])
+                          for h in ("k", "v")}
+                for j, (kind, _) in enumerate(self.cfg.block_pattern())
+                if kind == "attn"}
+
+    def _prefill_rows(self, prompt: np.ndarray):
+        """Prefill ONE request and lower its K/V to page rows: returns the
+        request's first generated token id, the page rows of every
+        attention block (``_cache_rows``: page ``k`` of superblock ``sb``
+        at ``[sb, k]``, ready to scatter at whatever tick the scheduler
+        lands that page — whole-prompt admission scatters all rows at once;
+        chunked prefill scatters slices as ``ev.prefill_chunks`` records
+        arrive) and the prompt's final SSM state of every Mamba block
+        (``{}`` for an attention-only model; ``_write_slots`` lands it).
+        Two jitted calls, one compile each per distinct prompt length.  K/V
+        slots past the prompt in its last page stay zero; every decode mask
+        is ``idx <= pos``, so a stale slot is never read before the decode
+        step that writes it."""
+        telemetry.count("engine.admit_dispatches", 2)
         with telemetry.span("engine.prefill"):
-            logits, cache = self._prefill(self.params,
-                                          jnp.asarray(prompt)[None])
-            first = int(jnp.argmax(logits[0, -1, :self.cfg.vocab_size]))
+            first, cache = self._prefill_first(self.params,
+                                               jnp.asarray(prompt)[None])
             with telemetry.span("engine.rows"):
-                rows, state = {}, {}
-                for j, (kind, _) in enumerate(self.cfg.block_pattern()):
-                    bc = cache["blocks"][f"b{j}"]
-                    if kind != "attn":
-                        state[f"b{j}"] = bc      # (n_superblocks, 1, ...)
-                        continue
-                    for sb in range(self.cfg.n_superblocks):
-                        rows[f"b{j}s{sb}"] = {"k": rows_of(bc["k"][sb]),
-                                              "v": rows_of(bc["v"][sb])}
+                rows = self._lower_rows(cache, int(prompt.shape[0]))
+            state = {f"b{j}": cache["blocks"][f"b{j}"]   # (n_sb, 1, ...)
+                     for j, (kind, _) in enumerate(self.cfg.block_pattern())
+                     if kind != "attn"}
+            first = int(first)
         return first, rows, state
 
     def _write_slot_rows(self, slots, state, lane):
@@ -423,27 +441,43 @@ class ServeEngine:
         """Land an admitted request's prefill state in its lane's SSM slot
         of every Mamba layer: one jitted call, its old slots donated."""
         telemetry.count("engine.ssm_slot_writes", 2 * len(self._ssm_keys))
+        telemetry.count("engine.admit_dispatches")
         with telemetry.span("engine.ssm_slot"):
             slots = {key: pools[key] for key in self._ssm_keys}
             return {**pools, **self._slot_write(slots, state, lane)}
 
+    def _scatter_pool_rows(self, kv_pools, rows, ids, page_start):
+        """Every KV pool with rows ``page_start .. page_start + len(ids)``
+        of its held prefill rows scattered at ``ids`` (jit'd once per row
+        and id count: ``page_start`` is traced; ``kv_pools`` is donated)."""
+        n = ids.shape[0]
+        out = {}
+        for j, (kind, _) in enumerate(self.cfg.block_pattern()):
+            if kind != "attn":
+                continue
+            for sb in range(self.cfg.n_superblocks):
+                key = f"b{j}s{sb}"
+                out[key] = {h: KV.scatter_pages(
+                    self.mem_arch, self.kv_cfg, kv_pools[key][h], ids,
+                    jax.lax.dynamic_slice_in_dim(rows[f"b{j}"][h][sb],
+                                                 page_start, n))
+                    for h in ("k", "v")}
+        return out
+
     def _scatter_rows(self, pools, rows, page_ids, page_start: int = 0):
-        """Scatter one contiguous slice of held prefill rows into every
+        """Scatter one contiguous slice of held prefill rows into every KV
         pool at the scheduler-allocated ids — the live half of a prefill
         chunk (or, with ``page_start=0`` and all ids, of a whole-prompt
-        admission)."""
-        telemetry.count("engine.scatter_calls", 2 * len(rows))
+        admission): one jitted call of one ``banked_scatter`` per pool and
+        K/V, the KV pools donated; a hybrid's SSM slots pass through."""
+        telemetry.count("engine.scatter_calls", 2 * self.n_kv_layers)
+        telemetry.count("engine.admit_dispatches")
         with telemetry.span("engine.scatter"):
-            ids = jnp.asarray(np.asarray(page_ids, np.int32))
-            n = int(ids.shape[0])
-            pools = dict(pools)
-            for key, pair in rows.items():
-                pools[key] = {
-                    h: KV.scatter_pages(
-                        self.mem_arch, self.kv_cfg, pools[key][h], ids,
-                        pair[h][page_start:page_start + n])
-                    for h in ("k", "v")}
-        return pools
+            kv_pools = {key: pair for key, pair in pools.items()
+                        if key not in self._ssm_keys}
+            return {**pools, **self._land_rows(
+                kv_pools, rows, np.asarray(page_ids, np.int32),
+                page_start)}
 
     def _ingest_request(self, pools, prompt: np.ndarray, page_ids,
                         rid: int | None = None, lane: int | None = None):
@@ -592,16 +626,18 @@ class ServeEngine:
                         "conv": jnp.zeros((self.max_batch, cfg.ssm_conv - 1,
                                            cfg.d_inner), dtype)}
                     continue
-                zero = jnp.zeros((self.kv_cfg.n_pages,)
+                # K and V are distinct buffers: admission donates both
+                pools[f"b{j}s{sb}"] = {
+                    h: jnp.zeros((self.kv_cfg.n_pages,)
                                  + self.kv_cfg.page_shape, dtype)
-                pools[f"b{j}s{sb}"] = {"k": zero, "v": zero}
+                    for h in ("k", "v")}
         scratch = jnp.asarray(sched.scratch_page or 0, jnp.int32)
         lane_tok = jnp.zeros((self.max_batch, 1), jnp.int32)
         lane_rid = np.full(self.max_batch, -1, np.int64)
         toks: dict[int, list] = {}
         outputs: dict[int, np.ndarray] = {}
         #: held prefill rows of lanes mid-chunked-prefill
-        #: (lane -> per-pool row arrays; see ``_prefill_rows``)
+        #: (lane -> per-block row arrays; see ``_prefill_rows``)
         pending: dict[int, dict] = {}
         #: a hybrid's held prefill SSM state of those lanes, written into
         #: the lane's slots when the last chunk lands

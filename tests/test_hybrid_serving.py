@@ -164,12 +164,16 @@ def _served_logits(eng, reqs, chunk=None, cancel=None):
     """Run a day; return (outputs, [(rid, position, logits)]) with the
     logits of every served token, prefill's first token included."""
     prefill, decode = [], []
-    run_prefill, run_decode = eng._prefill, eng._decode_sched
+    run_prefill, run_decode = eng._prefill_first, eng._decode_sched
 
     def rec_prefill(params, toks):
-        logits, cache = run_prefill(params, toks)
-        prefill.append(np.asarray(logits[0, -1]))
-        return logits, cache
+        # the admission's call returns the first token alone: its logits
+        # are the same prefill's, run again, and the token their argmax
+        first, cache = run_prefill(params, toks)
+        logits = np.asarray(eng._prefill(params, toks)[0][0, -1])
+        assert int(first) == int(np.argmax(logits[:eng.cfg.vocab_size]))
+        prefill.append(logits)
+        return first, cache
 
     def rec_decode(params, tok, pools, page_table, pos, active, scratch):
         logits, pools = run_decode(params, tok, pools, page_table, pos,
@@ -182,11 +186,11 @@ def _served_logits(eng, reqs, chunk=None, cancel=None):
                       n_kv_layers=eng.n_kv_layers,
                       n_ssm_layers=eng.n_ssm_layers,
                       prefill_chunk_pages=chunk, cancel=cancel)
-    eng._prefill, eng._decode_sched = rec_prefill, rec_decode
+    eng._prefill_first, eng._decode_sched = rec_prefill, rec_decode
     try:
         res = eng.run_scheduler(reqs, scheduler=sched)
     finally:
-        eng._prefill, eng._decode_sched = run_prefill, run_decode
+        eng._prefill_first, eng._decode_sched = run_prefill, run_decode
     plen = {r.rid: r.prompt_len for r in reqs}
     served = [(rid, plen[rid] - 1, lg)
               for rid, lg in zip(sched.admitted, prefill)]
