@@ -1,25 +1,23 @@
-"""Batched serving engine: continuous-batch prefill + jit'd decode loop over
-the banked paged-KV pool (paper mapping: KV pages = banks; docs/SERVING.md).
+"""Serving engine over the banked paged-KV pools (paper mapping: KV pages =
+banks; docs/SERVING.md).
 
-The engine pads a request batch to a fixed shape (static compile) and
-prefills per-request caches in one shot.  In the default ``kv_mode="paged"``
-the prefill K/V is ingested into per-layer bank-major page pools (one
-``banked_scatter`` per pool) and the decode loop performs **all** KV traffic
-through the registry kernels on those pools:
+There is one paged decode path, the lane-ragged step ``_scheduler_step``
+(jit'd once as ``_decode_sched``): per attention layer it gathers each
+lane's page list from the K and V pools (``kernels.get("banked_gather")``),
+attends at the lane's own position, and writes the lane's *current* page
+back (``kernels.get("banked_scatter")``, a read-modify-write append); a
+Mamba layer advances its lane-indexed SSM state slot.  ``run_scheduler``
+drives it for continuous batching; ``generate`` serves a fixed, padded
+batch as its all-lanes-active case.  Either way a prompt's K/V is lowered
+to page rows (``_lower_rows``) and scattered into the donated pools in one
+call (``_land_rows``), after which no dense KV cache exists.
 
-  * read: every step gathers each sequence's page list from the K and V
-    pools via ``kernels.get("banked_gather")`` (the paged-attention read);
-  * write: the new token's K/V is inserted into the gathered view and the
-    sequence's *current* page is written back via
-    ``kernels.get("banked_scatter")`` (a read-modify-write append).
-
-No dense (seq-contiguous) KV cache exists after prefill ingest.  Every
-decode step also records its exact ``repro.core.trace.AddressTrace``
-(``step_trace()`` / ``serving_trace()``), so ``arch.cost(trace)`` prices the
-serving traffic with the same model that prices the Table II/III kernels.
-
-``kv_mode="dense"`` keeps the pre-banked reference path (the oracle the
-paged path is pinned against in tests/test_serving_paged.py).
+Every decode step records its exact ``repro.core.trace.AddressTrace``
+(``step_trace()`` / ``serving_trace()`` / ``scheduler_stream()``), so
+``arch.cost(trace)`` prices the serving traffic with the same model that
+prices the Table II/III kernels.  ``kv_mode="dense"`` keeps the pre-banked
+dense-cache decode as the reference the paged path is pinned against
+(tests/test_serving_paged.py).
 """
 from __future__ import annotations
 
@@ -98,14 +96,20 @@ class ServeEngine:
         self._decode = jax.jit(
             lambda p, tok, cache, pos: T.decode_step(cfg, rc, p, tok, cache,
                                                      pos, ax))
-        self._decode_paged = jax.jit(self._paged_step)
         self._decode_sched = jax.jit(self._scheduler_step)
-        #: pool keys of the Mamba layers: in ``run_scheduler`` each holds
-        #: one lane-indexed SSM state slot per lane (slot = lane) instead
-        #: of a K/V page pool; empty for an attention-only model
-        self._ssm_keys = tuple(
-            f"b{j}s{sb}" for j, (kind, _) in enumerate(cfg.block_pattern())
-            if kind != "attn" for sb in range(cfg.n_superblocks))
+        #: a fixed batch's page-table advance before each decode step
+        self._grow_pages = jax.jit(self._next_pages)
+        layers = [(f"b{j}s{sb}", f"b{j}", sb, kind == "attn")
+                  for j, (kind, _) in enumerate(cfg.block_pattern())
+                  for sb in range(cfg.n_superblocks)]
+        #: pool key ``b{j}s{sb}`` -> (block ``b{j}``, superblock ``sb``)
+        self._layer_of = {key: (blk, sb) for key, blk, sb, _ in layers}
+        #: pool keys of the attention layers, each a K and a V page pool
+        self._kv_keys = tuple(key for key, _, _, attn in layers if attn)
+        #: pool keys of the Mamba layers: each holds one lane-indexed SSM
+        #: state slot per lane (slot = lane) instead of a K/V page pool;
+        #: empty for an attention-only model
+        self._ssm_keys = tuple(key for key, _, _, attn in layers if not attn)
         # the old slots are dead once written: donated, so a write costs
         # one lane's rows and not a copy of every slot
         self._slot_write = jax.jit(self._write_slot_rows, donate_argnums=0)
@@ -139,9 +143,8 @@ class ServeEngine:
 
     @property
     def n_kv_layers(self) -> int:
-        """Attention layers with a KV pool (pattern attn blocks × scan)."""
-        return self.cfg.n_superblocks * sum(
-            1 for kind, _ in self.cfg.block_pattern() if kind == "attn")
+        """Attention layers, each with a K and a V page pool."""
+        return len(self._kv_keys)
 
     @property
     def n_ssm_layers(self) -> int:
@@ -150,137 +153,18 @@ class ServeEngine:
 
     # -- paged decode path -------------------------------------------------
 
-    def _paged_attention_decode(self, cfg, p, x, cache, pos, ax, *,
-                                window: int = 0, pages=None):
-        """``L.attention_decode`` against the banked page pool: gather the
-        sequence's pages (banked_gather), insert the new token, attend,
-        write the current page back (banked_scatter).  Numerics match the
-        dense path — same einsums, masks, and dtypes."""
-        kv = self.kv_cfg
-        arch = self.mem_arch
-        b = x.shape[0]
-        plen = kv.page_len
-        n_pt = pages.page_table.shape[1]
-        s_all = n_pt * plen
-        kvh, hd = cfg.n_kv_heads, cfg.hd
-        q, k_new, v_new = L._qkv(cfg, p, x, pos[None], ax)
-        ids = jnp.maximum(pages.page_table, 0).reshape(-1)
-        ck = KV.gather_pages(arch, kv, cache["k"], ids)
-        cv = KV.gather_pages(arch, kv, cache["v"], ids)
-        ck = ck.reshape(b, s_all, kvh, hd)
-        cv = cv.reshape(b, s_all, kvh, hd)
-        hot = (jnp.arange(s_all) == pos)[None, :, None, None]
-        ck = jnp.where(hot, k_new.astype(ck.dtype), ck)
-        cv = jnp.where(hot, v_new.astype(cv.dtype), cv)
-        idx = jnp.arange(s_all)
-        valid = (idx[None, :] <= pos) & jnp.repeat(
-            pages.page_table >= 0, plen, axis=1)
-        if window:
-            valid &= (pos - idx[None, :]) < window
-        s = jnp.einsum("bqkgh,btkh->bkgqt", q,
-                       ck.astype(q.dtype)) / math.sqrt(hd)
-        s = L.softcap(s, cfg.attn_softcap)
-        s = jnp.where(valid[:, None, None, None, :], s, L.NEG_INF)
-        pr = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-        o = jnp.einsum("bkgqt,btkh->bqkgh", pr, cv.astype(q.dtype))
-        o = o.reshape(b, 1, cfg.n_heads, hd)
-        out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
-        # read-modify-write append: the current page goes back to the pool
-        pg = pos // plen
-        cur = jnp.maximum(pages.page_table[jnp.arange(b), pg], 0)
-        k_line = jax.lax.dynamic_slice_in_dim(ck, pg * plen, plen, axis=1)
-        v_line = jax.lax.dynamic_slice_in_dim(cv, pg * plen, plen, axis=1)
-        kp = KV.scatter_pages(arch, kv, cache["k"], cur,
-                              k_line.reshape((b,) + kv.page_shape))
-        vp = KV.scatter_pages(arch, kv, cache["v"], cur,
-                              v_line.reshape((b,) + kv.page_shape))
-        return out, {"k": kp, "v": vp}
-
-    def _paged_step(self, params, tok, pools, pages, ssm, pos):
-        """One full-model decode step over the page pools (jit'd once; pos
-        is traced).  Mirrors ``T.decode_step``'s superblock ordering."""
-        cfg, rc, ax = self.cfg, self.rc, self.ax
-        dtype = jnp.dtype(rc.compute_dtype)
-        need = (pages.seq_lens % self.kv_cfg.page_len) == 0
-        pages, _ = KV.allocate_pages(self.kv_cfg, pages, need)
-        x = params["embed"].astype(dtype)[tok]
-        pattern = cfg.block_pattern()
-        pools = dict(pools)
-        ssm_parts: dict = {f"b{j}": [] for j, (kind, _) in enumerate(pattern)
-                           if kind != "attn"}
-        attn_fn = functools.partial(self._paged_attention_decode, pages=pages)
-        for sb in range(cfg.n_superblocks):
-            for j, (kind, is_moe) in enumerate(pattern):
-                p_sb = jax.tree.map(lambda a: a[sb],
-                                    params["blocks"][f"b{j}"])
-                if kind == "attn":
-                    key = f"b{j}s{sb}"
-                    x, pools[key] = T.apply_block_decode(
-                        cfg, rc, p_sb, x, pools[key], pos, ax, kind, is_moe,
-                        j, attn_fn=attn_fn)
-                else:
-                    c_sb = jax.tree.map(lambda a: a[sb], ssm[f"b{j}"])
-                    x, nc = T.apply_block_decode(
-                        cfg, rc, p_sb, x, c_sb, pos, ax, kind, is_moe, j)
-                    ssm_parts[f"b{j}"].append(nc)
-        new_ssm = {k: jax.tree.map(lambda *xs: jnp.stack(xs), *v)
-                   for k, v in ssm_parts.items()}
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        logits = T._unembed(cfg, params, x)
-        pages = pages._replace(seq_lens=pages.seq_lens + 1)
-        return logits, pools, pages, new_ssm
-
-    def _ingest_prefill(self, cache, plen: int, batch: int):
-        """Allocate every prompt page and scatter the prefill K/V into the
-        per-layer pools (one banked_scatter per pool) — after this, the
-        dense prefill cache is dead and all KV state lives banked."""
-        kv = self.kv_cfg
-        plen_pg = kv.page_len
-        n_pref = -(-plen // plen_pg)
-        pages = KV.init_pages(kv, batch, self.max_seq)
-        ones = jnp.ones((batch,), bool)
-        for p in range(n_pref):
-            pages = pages._replace(
-                seq_lens=jnp.full((batch,), p * plen_pg, jnp.int32))
-            pages, _ = KV.allocate_pages(kv, pages, ones)
-        pages = pages._replace(
-            seq_lens=jnp.full((batch,), plen, jnp.int32))
-        ids = jnp.maximum(pages.page_table[:, :n_pref], 0).reshape(-1)
-
-        def pool_of(kc):
-            # kc: (B, t, KV, HD) with t ≤ plen (SWA prefill keeps only the
-            # window; earlier slots stay zero and are window-masked anyway)
-            t = kc.shape[1]
-            buf = jnp.zeros((batch, n_pref * plen_pg) + kc.shape[2:],
-                            kc.dtype)
-            buf = buf.at[:, plen - t:plen].set(kc)
-            rows = buf.reshape((batch * n_pref,) + kv.page_shape)
-            pool = jnp.zeros((kv.n_pages,) + kv.page_shape, kc.dtype)
-            return KV.scatter_pages(self.mem_arch, kv, pool, ids, rows)
-
-        pools, ssm = {}, {}
-        for j, (kind, _) in enumerate(self.cfg.block_pattern()):
-            bc = cache["blocks"][f"b{j}"]
-            if kind != "attn":
-                ssm[f"b{j}"] = bc
-                continue
-            for sb in range(self.cfg.n_superblocks):
-                pools[f"b{j}s{sb}"] = {"k": pool_of(bc["k"][sb]),
-                                       "v": pool_of(bc["v"][sb])}
-        return pools, pages, ssm
-
-    # -- continuous-batching (lane-ragged) decode path -----------------------
-
     def _paged_attention_decode_ragged(self, cfg, p, x, cache, pos, ax, *,
                                        window: int = 0, page_table=None,
                                        active=None, scratch=0):
-        """``_paged_attention_decode`` with per-lane positions: each lane
-        attends up to its OWN sequence position (``pos`` is (B,), not a
-        scalar) and writes back its own current page.  Lanes with no
-        resident sequence (``active`` False) insert nothing and scatter to
-        the reserved ``scratch`` page — the Pallas scatter has no lane
-        predication, so idle lanes need a harmless sink (the trace
-        predicates them off; see ``scheduler.scheduler_step_trace``)."""
+        """``L.attention_decode`` against the banked page pools, with
+        per-lane positions: gather each lane's pages (banked_gather),
+        insert the new token at the lane's OWN position (``pos`` is (B,)),
+        attend, and write the lane's current page back (banked_scatter).
+        Numerics match the dense path — same einsums, masks and dtypes.
+        Lanes with no resident sequence (``active`` False) insert nothing
+        and scatter to the reserved ``scratch`` page — the Pallas scatter
+        has no lane predication, so idle lanes need a harmless sink (the
+        trace predicates them off; see ``kvcache.decode_step_trace``)."""
         kv = self.kv_cfg
         arch = self.mem_arch
         b = x.shape[0]
@@ -335,9 +219,8 @@ class ServeEngine:
         """One lane-ragged full-model decode step (jit'd once; the page
         table, per-lane positions and active mask are traced values with
         static shapes, so admissions/completions never recompile).  The
-        host-side ``scheduler.Scheduler`` owns allocation — unlike
-        ``_paged_step`` there is no in-graph ``allocate_pages``.  A Mamba
-        layer's ``pools`` entry is its lane-indexed SSM state
+        host side owns allocation: there is no in-graph ``allocate_pages``.
+        A Mamba layer's ``pools`` entry is its lane-indexed SSM state
         (``{"h": (B, Di, N) f32, "conv": (B, K-1, Di)}``), advanced for
         the ``active`` lanes only."""
         cfg, rc, ax = self.cfg, self.rc, self.ax
@@ -377,20 +260,21 @@ class ServeEngine:
         return jnp.argmax(logits[0, -1, :self.cfg.vocab_size]), cache
 
     def _cache_rows(self, cache, plen: int):
-        """Every attention block's stacked K/V prefill cache
-        ``(n_superblocks, 1, t, KV, HD)`` lowered to page rows
-        ``(n_superblocks, n_pref) + page_shape``, keyed ``b{j}`` (jit'd
-        once per prompt length ``plen``).  ``t`` ≤ ``plen``: SWA keeps only
-        the window, so the rows before it and past the prompt stay zero.
-        One superblock at a time (``lax.map``): lowering the whole stack
-        at once makes the TPU compiler stage a relayout copy of it."""
+        """Every attention block's stacked K/V prefill cache of ``B``
+        prompts ``(n_superblocks, B, t, KV, HD)`` lowered to page rows
+        ``(n_superblocks, B · n_pref) + page_shape`` (prompt ``b``'s page
+        ``k`` at row ``b · n_pref + k``), keyed ``b{j}`` (jit'd once per
+        batch and prompt length ``plen``).  ``t`` ≤ ``plen``: SWA keeps
+        only the window, so the rows before it and past the prompt stay
+        zero.  One superblock at a time (``lax.map``): lowering the whole
+        stack at once makes the TPU compiler stage a relayout copy of it."""
         kv = self.kv_cfg
         n_pref = -(-plen // kv.page_len)
 
-        def lower(c):                   # one superblock: (1, t, KV, HD)
+        def lower(c):                   # one superblock: (B, t, KV, HD)
             pad = [(0, 0)] * c.ndim
             pad[1] = (plen - c.shape[1], n_pref * kv.page_len - plen)
-            return jnp.pad(c, pad).reshape((n_pref,) + kv.page_shape)
+            return jnp.pad(c, pad).reshape((-1,) + kv.page_shape)
 
         return {f"b{j}": {h: jax.lax.map(lower, cache["blocks"][f"b{j}"][h])
                           for h in ("k", "v")}
@@ -426,15 +310,11 @@ class ServeEngine:
         """Every Mamba layer's slot with lane ``lane``'s row replaced by
         one prefill's final state (jit'd once: ``lane`` is traced)."""
         out = {}
-        for j, (kind, _) in enumerate(self.cfg.block_pattern()):
-            if kind == "attn":
-                continue
-            for sb in range(self.cfg.n_superblocks):
-                key = f"b{j}s{sb}"
-                out[key] = {
-                    name: slot.at[lane].set(
-                        state[f"b{j}"][name][sb, 0].astype(slot.dtype))
-                    for name, slot in slots[key].items()}
+        for key in self._ssm_keys:
+            blk, sb = self._layer_of[key]
+            out[key] = {name: slot.at[lane].set(
+                state[blk][name][sb, 0].astype(slot.dtype))
+                for name, slot in slots[key].items()}
         return out
 
     def _write_slots(self, pools, state, lane: int):
@@ -452,16 +332,12 @@ class ServeEngine:
         and id count: ``page_start`` is traced; ``kv_pools`` is donated)."""
         n = ids.shape[0]
         out = {}
-        for j, (kind, _) in enumerate(self.cfg.block_pattern()):
-            if kind != "attn":
-                continue
-            for sb in range(self.cfg.n_superblocks):
-                key = f"b{j}s{sb}"
-                out[key] = {h: KV.scatter_pages(
-                    self.mem_arch, self.kv_cfg, kv_pools[key][h], ids,
-                    jax.lax.dynamic_slice_in_dim(rows[f"b{j}"][h][sb],
-                                                 page_start, n))
-                    for h in ("k", "v")}
+        for key in self._kv_keys:
+            blk, sb = self._layer_of[key]
+            out[key] = {h: KV.scatter_pages(
+                self.mem_arch, self.kv_cfg, kv_pools[key][h], ids,
+                jax.lax.dynamic_slice_in_dim(rows[blk][h][sb], page_start, n))
+                for h in ("k", "v")}
         return out
 
     def _scatter_rows(self, pools, rows, page_ids, page_start: int = 0):
@@ -473,8 +349,7 @@ class ServeEngine:
         telemetry.count("engine.scatter_calls", 2 * self.n_kv_layers)
         telemetry.count("engine.admit_dispatches")
         with telemetry.span("engine.scatter"):
-            kv_pools = {key: pair for key, pair in pools.items()
-                        if key not in self._ssm_keys}
+            kv_pools = {key: pools[key] for key in self._kv_keys}
             return {**pools, **self._land_rows(
                 kv_pools, rows, np.asarray(page_ids, np.int32),
                 page_start)}
@@ -492,6 +367,25 @@ class ServeEngine:
                 pools = self._write_slots(pools, state, lane)
             return pools, first
 
+    def _init_pools(self, batch: int):
+        """Zero pools for ``batch`` lanes: a K and a V page pool per
+        attention layer, and per Mamba layer a lane-indexed SSM state slot
+        (``{"h": (batch, Di, N) f32, "conv": (batch, K-1, Di)}``)."""
+        cfg, dtype = self.cfg, jnp.dtype(self.rc.compute_dtype)
+
+        def zeros(key):
+            if key in self._ssm_keys:
+                return {"h": jnp.zeros((batch, cfg.d_inner, cfg.ssm_state),
+                                       jnp.float32),
+                        "conv": jnp.zeros((batch, cfg.ssm_conv - 1,
+                                           cfg.d_inner), dtype)}
+            # K and V are distinct buffers: admission donates both
+            return {h: jnp.zeros((self.kv_cfg.n_pages,)
+                                 + self.kv_cfg.page_shape, dtype)
+                    for h in ("k", "v")}
+
+        return {key: zeros(key) for key in self._layer_of}
+
     def _migrate_pages(self, pools, old_ids, new_ids):
         """Evacuate a dying bank's live pages: gather each page's row from
         its old id and scatter it to the freshly allocated surviving-bank
@@ -502,14 +396,12 @@ class ServeEngine:
         old = jnp.asarray(np.asarray(old_ids, np.int32))
         new = jnp.asarray(np.asarray(new_ids, np.int32))
         pools = dict(pools)
-        for key, pair in pools.items():
-            if key in self._ssm_keys:        # SSM slots hold no pages
-                continue
+        for key in self._kv_keys:           # SSM slots hold no pages
             out = {}
-            for half in ("k", "v"):
-                rows = KV.gather_pages(self.mem_arch, kv, pair[half], old)
-                out[half] = KV.scatter_pages(self.mem_arch, kv, pair[half],
-                                             new, rows)
+            for half, pool in pools[key].items():
+                rows = KV.gather_pages(self.mem_arch, kv, pool, old)
+                out[half] = KV.scatter_pages(self.mem_arch, kv, pool, new,
+                                             rows)
             pools[key] = out
         return pools
 
@@ -614,23 +506,7 @@ class ServeEngine:
             raise ValueError(
                 f"the scheduler carries {sched.n_ssm_layers} SSM layers, "
                 f"the model {self.n_ssm_layers}")
-        dtype = jnp.dtype(self.rc.compute_dtype)
-        cfg = self.cfg
-        pools = {}
-        for j, (kind, _) in enumerate(cfg.block_pattern()):
-            for sb in range(cfg.n_superblocks):
-                if kind != "attn":
-                    pools[f"b{j}s{sb}"] = {
-                        "h": jnp.zeros((self.max_batch, cfg.d_inner,
-                                        cfg.ssm_state), jnp.float32),
-                        "conv": jnp.zeros((self.max_batch, cfg.ssm_conv - 1,
-                                           cfg.d_inner), dtype)}
-                    continue
-                # K and V are distinct buffers: admission donates both
-                pools[f"b{j}s{sb}"] = {
-                    h: jnp.zeros((self.kv_cfg.n_pages,)
-                                 + self.kv_cfg.page_shape, dtype)
-                    for h in ("k", "v")}
+        pools = self._init_pools(self.max_batch)
         scratch = jnp.asarray(sched.scratch_page or 0, jnp.int32)
         lane_tok = jnp.zeros((self.max_batch, 1), jnp.int32)
         lane_rid = np.full(self.max_batch, -1, np.int64)
@@ -826,7 +702,13 @@ class ServeEngine:
     def generate(self, prompts: np.ndarray, max_new_tokens: int = 32,
                  temperature: float = 0.0,
                  seed: int = 0) -> GenerationResult:
-        """prompts: (B, prompt_len) int32 (pre-padded request batch)."""
+        """prompts: (B, prompt_len) int32 (pre-padded request batch).
+
+        Paged, a fixed batch is the all-lanes-active case of
+        ``run_scheduler``'s step: its prompts land as an admission lands
+        one (``_land_prompts``), and each decode step is ``_decode_sched``
+        with every lane at the batch's one position, after ``_grow_pages``
+        has given each lane at a page boundary its next page."""
         b, plen = prompts.shape
         assert b <= self.max_batch and plen + max_new_tokens <= self.max_seq
         logits, cache = self._prefill(self.params, jnp.asarray(prompts))
@@ -836,8 +718,9 @@ class ServeEngine:
         out.append(tok)
         paged = self.kv_mode == "paged"
         if paged:
-            pools, pages, ssm = self._ingest_prefill(cache, plen, b)
+            pools, pages = self._land_prompts(cache, plen, b)
             del cache                       # no dense KV survives prefill
+            active = jnp.ones((b,), bool)
             self._step_traces = []
             self._prefill_trace = KV.prefill_trace(
                 self.kv_cfg, np.asarray(pages.page_table), plen,
@@ -845,15 +728,18 @@ class ServeEngine:
         else:
             cache = self._pad_cache(cache, plen)
         for i in range(1, max_new_tokens):
-            pos = jnp.asarray(plen + i - 1, jnp.int32)
             if paged:
-                logits, pools, pages, ssm = self._decode_paged(
-                    self.params, tok, pools, pages, ssm, pos)
+                pos = pages.seq_lens
+                pages = self._grow_pages(pages)
+                logits, pools = self._decode_sched(
+                    self.params, tok, pools, pages.page_table, pos, active, 0)
                 self._step_traces.append(KV.decode_step_trace(
                     self.kv_cfg, np.asarray(pages.page_table), plen + i - 1,
                     self.n_kv_layers))
             else:
-                logits, cache = self._decode(self.params, tok, cache, pos)
+                logits, cache = self._decode(
+                    self.params, tok, cache,
+                    jnp.asarray(plen + i - 1, jnp.int32))
             key, sub = jax.random.split(key)
             tok = self._sample(logits[:, -1], temperature, sub)
             out.append(tok)
@@ -862,6 +748,36 @@ class ServeEngine:
         tokens = np.concatenate([np.asarray(t) for t in out], axis=1)
         return GenerationResult(tokens=tokens, prompt_len=plen,
                                 steps=max_new_tokens)
+
+    def _land_prompts(self, cache, plen: int, batch: int):
+        """Land a fixed batch's prefill ``cache`` in fresh pools: every
+        prompt page allocated in one call (``KV.allocate_prompt_pages``),
+        the K/V lowered to page rows (``_lower_rows``) and scattered into
+        the donated KV pools in one call (``_land_rows``), and each Mamba
+        layer's slots set to the prompts' final state.  Returns the pools
+        and the page table."""
+        kv = self.kv_cfg
+        pages = KV.allocate_prompt_pages(kv, batch, self.max_seq, plen)
+        n_pref = -(-plen // kv.page_len)
+        ids = jnp.maximum(pages.page_table[:, :n_pref], 0).reshape(-1)
+        pools = self._init_pools(batch)
+        pools.update(self._land_rows(
+            {key: pools[key] for key in self._kv_keys},
+            self._lower_rows(cache, plen), ids, 0))
+        for key in self._ssm_keys:
+            blk, sb = self._layer_of[key]
+            pools[key] = {name: cache["blocks"][blk][name][sb].astype(
+                slot.dtype) for name, slot in pools[key].items()}
+        return pools, pages
+
+    def _next_pages(self, pages: KV.PageTableState) -> KV.PageTableState:
+        """A fixed batch's page table for its next decode step: every lane
+        at a page boundary gets its next page (``KV.allocate_pages``), and
+        ``seq_lens`` moves past the step's token (jit'd as
+        ``_grow_pages``)."""
+        need = (pages.seq_lens % self.kv_cfg.page_len) == 0
+        pages, _ = KV.allocate_pages(self.kv_cfg, pages, need)
+        return pages._replace(seq_lens=pages.seq_lens + 1)
 
     def _sample(self, logits, temperature: float, key):
         logits = logits[..., :self.cfg.vocab_size]

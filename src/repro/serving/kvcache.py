@@ -399,24 +399,34 @@ def kv_read_stream(page_table) -> tuple[np.ndarray, np.ndarray]:
     return np.maximum(pt, 0).reshape(-1), (pt >= 0).reshape(-1)
 
 
-def decode_step_trace(cfg: PagedKVConfig, page_table, pos: int,
-                      n_kv_layers: int = 1):
+def decode_step_trace(cfg: PagedKVConfig, page_table, pos,
+                      n_kv_layers: int = 1, active=None):
     """One decode step's exact ``AddressTrace``.
 
-    Per KV layer, in kernel-call order: a K-pool page gather, a V-pool page
-    gather (the paged-attention read), then a K and a V scatter of the
-    sequence's *current* page (the read-modify-write append).  Addresses are
-    logical pool page ids — the banked unit — produced by the registry
-    kernels' own trace generators, so ``arch.cost`` prices serving exactly
-    like any other kernel.
+    Per KV layer, in kernel-call order: a K-pool and a V-pool page gather
+    (the paged-attention read: every lane reads its own page list;
+    unmapped entries and inactive lanes are predicated off — a SIMT lane
+    with no resident sequence issues no request), then a K and a V scatter
+    of each active lane's *current* page (the read-modify-write append at
+    that lane's position).  ``pos`` is one position shared by every lane
+    (a fixed batch) or one per lane; ``active`` masks lanes and defaults
+    to all of them.  Addresses are logical pool page ids — the banked
+    unit — produced by the registry kernels' own trace generators, so
+    ``arch.cost`` prices serving exactly like any other kernel.
     """
     from repro.core.trace import AddressTrace
     from repro.kernels.banked_gather.ops import banked_gather_trace
     from repro.kernels.banked_scatter.ops import banked_scatter_trace
     pt = np.asarray(page_table)
-    b = pt.shape[0]
+    b, width = pt.shape
+    lane_pos = np.broadcast_to(np.asarray(pos), (b,))
+    active = (np.ones(b, bool) if active is None
+              else np.asarray(active, bool))
     read_ids, read_mask = kv_read_stream(pt)
-    cur = pt[np.arange(b), int(pos) // cfg.page_len]
+    read_mask = read_mask & np.repeat(active, width)
+    cur = np.where(active, pt[np.arange(b),
+                              np.minimum(lane_pos // cfg.page_len,
+                                         width - 1)], -1)
     cur_ids, cur_mask = np.maximum(cur, 0), cur >= 0
     chunks = []
     for _ in range(n_kv_layers):
@@ -429,8 +439,9 @@ def decode_step_trace(cfg: PagedKVConfig, page_table, pos: int,
         chunks.append(banked_scatter_trace(None, None, cur_ids,
                                            mask=cur_mask))
     t = AddressTrace.concat(*chunks)
-    t.meta.update({"what": "decode_step", "pos": int(pos),
-                   "n_kv_layers": n_kv_layers})
+    t.meta.update({"what": "decode_step", "n_kv_layers": n_kv_layers})
+    if np.ndim(pos) == 0:
+        t.meta["pos"] = int(pos)
     return t
 
 

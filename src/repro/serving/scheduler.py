@@ -50,8 +50,8 @@ import numpy as np
 
 from repro.runtime import telemetry
 from repro.runtime.faults import FaultEvent, FaultPlan
-from repro.serving.kvcache import (PagedKVConfig, kv_read_stream, pool_pages,
-                                   resolve_policy)
+from repro.serving.kvcache import (PagedKVConfig, decode_step_trace,
+                                   pool_pages, resolve_policy)
 
 __all__ = [
     "Request", "Admission", "Completion", "TickEvent",
@@ -345,41 +345,14 @@ def fault_migrate_trace(cfg: PagedKVConfig, old_ids, new_ids,
 def scheduler_step_trace(cfg: PagedKVConfig, page_table, pos, active,
                          n_kv_layers: int = 1, tick: int | None = None,
                          degraded: bool = False):
-    """One lane-ragged decode step's exact ``AddressTrace``.
-
-    Generalizes ``kvcache.decode_step_trace`` to per-lane positions and an
-    active-lane mask: per KV layer, a K- and a V-pool page-list gather
-    (lanes read their own page lists; unmapped and inactive lanes are
-    predicated off — a SIMT lane with no resident sequence issues no
-    request) followed by a K and a V scatter of each active lane's
-    *current* page (the read-modify-write append at that lane's own
-    position).  Addresses are logical pool page ids.
-    """
-    from repro.core.trace import AddressTrace
-    from repro.kernels.banked_gather.ops import banked_gather_trace
-    from repro.kernels.banked_scatter.ops import banked_scatter_trace
+    """One lane-ragged decode step's exact ``AddressTrace``:
+    ``kvcache.decode_step_trace`` at each lane's own position with the
+    idle lanes predicated off, tagged with the tick (``degraded`` after a
+    bank loss)."""
     with telemetry.span("sched.lower"):
-        pt = np.asarray(page_table)
-        pos = np.asarray(pos)
         active = np.asarray(active, bool)
-        b = pt.shape[0]
-        read_ids, read_mask = kv_read_stream(pt)
-        read_mask = read_mask & np.repeat(active, pt.shape[1])
-        cur = np.where(active, pt[np.arange(b),
-                                  np.minimum(pos // cfg.page_len,
-                                             pt.shape[1] - 1)], -1)
-        cur_ids, cur_mask = np.maximum(cur, 0), cur >= 0
-        chunks = []
-        for _ in range(n_kv_layers):
-            chunks.append(banked_gather_trace(None, None, read_ids,
-                                              mask=read_mask))
-            chunks.append(banked_gather_trace(None, None, read_ids,
-                                              mask=read_mask))
-            chunks.append(banked_scatter_trace(None, None, cur_ids,
-                                               mask=cur_mask))
-            chunks.append(banked_scatter_trace(None, None, cur_ids,
-                                               mask=cur_mask))
-        t = AddressTrace.concat(*chunks)
+        t = decode_step_trace(cfg, page_table, pos, n_kv_layers,
+                              active=active)
     t.meta.update({"what": ("sched_decode_degraded" if degraded
                             else "sched_decode"), "tick": tick,
                    "active": int(active.sum()), "n_kv_layers": n_kv_layers})

@@ -52,15 +52,19 @@ def test_paged_step_logits_match_dense():
                       kv_mode="paged", page_len=8)
     plen = PROMPTS.shape[1]
     logits0, cache = eng._prefill(eng.params, jnp.asarray(PROMPTS))
-    pools, pages, ssm = eng._ingest_prefill(cache, plen, PROMPTS.shape[0])
+    pools, pages = eng._land_prompts(cache, plen, PROMPTS.shape[0])
     cache_d = eng._pad_cache(cache, plen)
     tok = jnp.argmax(logits0[:, -1, :CFG.vocab_size],
                      axis=-1).astype(jnp.int32)[:, None]
+    every_lane = jnp.ones((PROMPTS.shape[0],), bool)
     for i in range(1, 6):
         pos = jnp.asarray(plen + i - 1, jnp.int32)
         ld, cache_d = eng._decode(eng.params, tok, cache_d, pos)
-        lp, pools, pages, ssm = eng._decode_paged(eng.params, tok, pools,
-                                                  pages, ssm, pos)
+        lane_pos = pages.seq_lens
+        pages = eng._grow_pages(pages)
+        lp, pools = eng._decode_sched(eng.params, tok, pools,
+                                      pages.page_table, lane_pos,
+                                      every_lane, 0)
         np.testing.assert_allclose(np.asarray(lp), np.asarray(ld),
                                    rtol=2e-4, atol=2e-5)
         np.testing.assert_array_equal(
@@ -78,7 +82,7 @@ def test_prefill_ingest_is_bitexact_across_page_boundary():
     eng = _engine("paged")
     plen = PROMPTS.shape[1]
     _, cache = eng._prefill(eng.params, jnp.asarray(PROMPTS))
-    pools, pages, _ = eng._ingest_prefill(cache, plen, PROMPTS.shape[0])
+    pools, pages = eng._land_prompts(cache, plen, PROMPTS.shape[0])
     kv = eng.kv_cfg
     n_pref = -(-plen // kv.page_len)
     ids = jnp.maximum(pages.page_table[:, :n_pref], 0).reshape(-1)
@@ -133,6 +137,66 @@ def test_paged_matches_dense_with_sliding_windows():
     np.testing.assert_array_equal(
         d.generate(prompts, max_new_tokens=6).tokens,
         p.generate(prompts, max_new_tokens=6).tokens)
+
+
+@pytest.mark.parametrize("name", ["jamba-v0.1-52b", "falcon-mamba-7b"])
+def test_paged_generate_matches_dense_on_ssm_models(name):
+    """A model with Mamba layers serves a fixed batch through the same
+    step as ``run_scheduler``: each Mamba layer's lane slots start from
+    the batch prefill's state and advance every step, and the greedy
+    tokens equal the dense reference's."""
+    cfg = get_smoke_config(name)
+    assert any(kind != "attn" for kind, _ in cfg.block_pattern())
+    params = init_tree(model_specs(cfg), jax.random.PRNGKey(0))
+    prompts = np.random.default_rng(2).integers(
+        0, cfg.vocab_size, size=(2, 12)).astype(np.int32)
+    d = ServeEngine(cfg, RC, params, NO_AXES, max_batch=2, max_seq=32,
+                    kv_mode="dense")
+    p = ServeEngine(cfg, RC, params, NO_AXES, max_batch=2, max_seq=32,
+                    kv_mode="paged", page_len=8)
+    np.testing.assert_array_equal(
+        d.generate(prompts, max_new_tokens=6).tokens,
+        p.generate(prompts, max_new_tokens=6).tokens)
+
+
+def test_paged_generate_dispatches_the_scheduler_step():
+    """Every decode step of a fixed batch is one call of the jitted
+    lane-ragged step that ``run_scheduler`` drives: ``max_new_tokens - 1``
+    calls (the first token comes from prefill), each with every lane
+    active."""
+    eng = _engine("paged")
+    calls = []
+    step = eng._decode_sched
+
+    def counted(*args):
+        calls.append(np.asarray(args[5]))
+        return step(*args)
+
+    eng._decode_sched = counted
+    eng.generate(PROMPTS, max_new_tokens=7)
+    assert len(calls) == 6
+    assert all(active.shape == (PROMPTS.shape[0],) and active.all()
+               for active in calls)
+
+
+def test_fixed_batch_step_trace_is_the_all_active_scheduler_step():
+    """One decode-step lowering: the fixed batch's ``decode_step_trace``
+    with every lane active equals ``scheduler_step_trace`` on the same
+    page table and positions, op for op."""
+    from repro.serving.kvcache import decode_step_trace
+    from repro.serving.scheduler import scheduler_step_trace
+    eng = _engine("paged")
+    eng.generate(PROMPTS, max_new_tokens=8)
+    pt = np.asarray(eng.last_pages.page_table)
+    b = pt.shape[0]
+    for pos in (12, 16, 18):
+        fixed = decode_step_trace(eng.kv_cfg, pt, pos, eng.n_kv_layers)
+        sched = scheduler_step_trace(eng.kv_cfg, pt, np.full(b, pos),
+                                     np.ones(b, bool), eng.n_kv_layers)
+        np.testing.assert_array_equal(fixed.addrs, sched.addrs)
+        np.testing.assert_array_equal(fixed.kinds, sched.kinds)
+        np.testing.assert_array_equal(np.asarray(fixed.mask),
+                                      np.asarray(sched.mask))
 
 
 def test_dense_mode_has_no_traces():
